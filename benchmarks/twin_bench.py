@@ -12,9 +12,13 @@ compilation cache), and `batched_query_ms_per_item` (multi-tenant
 throughput through the vmapped batch program).
 
 Cold timings run in SUBPROCESSES so each one sees a true fresh
-process: the cold run points ``REPRO_COMPILE_CACHE_DIR`` at an empty
-temp dir (nothing to deserialize), the cached-cold run inherits the
-default ``results/compile_cache/`` dir this process just populated.
+process, and they all run before this process starts a JAX backend:
+a chip belongs to one process at a time, so a child could not open it
+while the parent held it.  The cold run turns jax's compilation cache
+off (``JAX_ENABLE_COMPILATION_CACHE=false``: nothing to deserialize);
+a second child populates the persistent cache
+(`compat.compile_cache_dir`) and a third, the cached-cold run, reads
+it back.
 
 BENCH_twin.json schema (one JSON object):
   n_combos         int   design points per query (full default grid)
@@ -39,7 +43,7 @@ BENCH_twin.json schema (one JSON object):
   batch_k          int   batch size used for the batched metric
   xla_step_us      float warm_query_ms amortized per (combo x step)
   pallas_step_us   float same for backend="pallas" on a reduced grid
-                         (interpret mode off-TPU; indicative only)
+                         (interpret mode on the CPU; indicative only)
   front_size       int   non-dominated set size of the base grid
   traces           int   retraces counted across the timed warm /
                          what-if / batched queries (the zero-retrace
@@ -54,7 +58,6 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 import time
 from pathlib import Path
 
@@ -73,21 +76,36 @@ print(json.dumps({"cold_ms": (time.perf_counter() - t0) * 1e3}))
 """ % BENCH_DT_S
 
 
-def _cold_subprocess(cache_dir: str | None) -> float:
+def _cold_subprocess(cache: bool) -> float:
     """Construct the default twin in a FRESH python process and return
-    the cold first-query latency.  `cache_dir` overrides the persistent
-    compile cache root (point it at an empty temp dir for a true cold
-    compile); None inherits the default results/compile_cache/."""
+    the cold first-query latency.  `cache=False` turns jax's
+    compilation cache off in the child (a true cold compile); True
+    leaves the persistent cache on, reading and filling
+    `compat.compile_cache_dir()`."""
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC)
-    env.pop("REPRO_COMPILE_CACHE", None)
-    if cache_dir is not None:
-        env["REPRO_COMPILE_CACHE_DIR"] = cache_dir
+    if not cache:
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     out = subprocess.run([sys.executable, "-c", _COLD_SCRIPT],
                          env=env, capture_output=True, text=True,
                          timeout=600, check=True)
     return float(json.loads(out.stdout.strip().splitlines()[-1])
                  ["cold_ms"])
+
+
+def _check_chip_free() -> None:
+    """The cold children need the device; a chip belongs to one process
+    at a time, so they must run before this process opens one."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() \
+            and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "twin_bench.run() starts cold-start child processes that "
+            "need the accelerator this process already holds; run it "
+            "in a fresh process (python benchmarks/twin_bench.py)")
 
 
 def _best_ms(fn, n: int = 5) -> float:
@@ -114,14 +132,18 @@ def _point_whatifs(daysim, k: int, start: int = 0) -> list:
 
 
 def run(n_repeats: int = 5):
+    # every child runs before this process touches the device: true
+    # cold (cache off), then one run that fills the persistent cache,
+    # then the restart latency through that warm disk cache
+    _check_chip_free()
+    cold_query_ms = _cold_subprocess(cache=False)
+    _cold_subprocess(cache=True)
+    cached_cold_query_ms = _cold_subprocess(cache=True)
+
     from repro.core import daysim
     from repro.serving.twin import DesignTwin
 
-    # true cold: fresh process, empty compile cache
-    with tempfile.TemporaryDirectory() as tmp:
-        cold_query_ms = _cold_subprocess(tmp)
-
-    twin = DesignTwin(dt_s=BENCH_DT_S)      # populates the default cache
+    twin = DesignTwin(dt_s=BENCH_DT_S)
     rep = twin.query()
     n, steps = len(rep), int(round(rep.day_hours.max() * 3600 / BENCH_DT_S))
 
@@ -150,9 +172,6 @@ def run(n_repeats: int = 5):
 
     batched_ms = _best_ms(batched, n_repeats)
     traces = daysim.EXEC_STATS["traces"] - traces0
-
-    # restart latency: fresh process, the disk cache populated above
-    cached_cold_query_ms = _cold_subprocess(None)
 
     # pallas kernel path on a reduced grid (interpret mode on CPU is an
     # emulation — indicative, not hardware-representative)

@@ -34,8 +34,11 @@ from dataclasses import replace
 
 import numpy as np
 
+from repro import compat
 from repro.core import fleet, montecarlo
 from repro.core.autoscale import AutoscalerSpec
+
+compat.enable_persistent_cache()
 
 N_USERS = 100_000
 FLEET_SIZE = 1_000_000.0

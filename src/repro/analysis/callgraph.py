@@ -37,10 +37,8 @@ _TRACERS = {
     "jax.experimental.shard_map.shard_map": "shard_map",
     "jax.experimental.pallas.pallas_call": "pallas",
 }
-# suffix fallbacks for repo-local wrappers (repro.compat.shard_map etc.)
+# suffix fallbacks for aliased imports
 _TRACER_SUFFIXES = {
-    "compat.shard_map": "shard_map",
-    "_compat_shard_map": "shard_map",
     "pl.pallas_call": "pallas",
     "lax.scan": "scan",
 }

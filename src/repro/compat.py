@@ -1,7 +1,7 @@
-"""Small jax version-compat shims shared across the package.
+"""Small jax shims shared across the package.
 
-The repo targets a range of jax releases; APIs that moved or were
-renamed get one adapter here so the next rename is a one-line fix.
+APIs the package wraps in one place so a jax change is a one-line fix:
+the persistent compilation cache, `shard_map`, and mesh construction.
 """
 from __future__ import annotations
 
@@ -10,76 +10,46 @@ from pathlib import Path
 
 import jax
 
-_CACHE_ENABLED: Path | None = None
-
 
 def compile_cache_dir() -> Path:
-    """Default persistent-compile-cache directory: version-keyed under
-    results/compile_cache/ (a jax upgrade invalidates by construction,
-    so stale executables are never deserialized).  Override the root
-    with ``REPRO_COMPILE_CACHE_DIR``."""
-    root = os.environ.get("REPRO_COMPILE_CACHE_DIR")
-    if root is None:
-        root = Path(__file__).resolve().parents[2] / "results" \
-            / "compile_cache"
-    return Path(root) / f"jax-{jax.__version__}"
+    """The persistent-compile-cache directory: ``JAX_COMPILATION_CACHE_DIR``
+    when it is set, else ``results/compile_cache/jax-<version>/`` in the
+    checkout (version-keyed, so a jax upgrade never deserializes stale
+    executables; a fixed path, because the path is part of the key)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return Path(env)
+    return (Path(__file__).resolve().parents[2] / "results"
+            / "compile_cache" / f"jax-{jax.__version__}")
 
 
-def enable_persistent_cache() -> Path | None:
-    """Point jax's persistent compilation cache at the repo-local
-    version-keyed directory so a process restart deserializes warm
-    executables from disk instead of recompiling (~19 s cold twin
-    query -> ~1 s).  Idempotent; returns the cache dir, or None when
-    opted out with ``REPRO_COMPILE_CACHE=0``.
+def enable_persistent_cache() -> Path:
+    """Turn on jax's persistent compilation cache for this process and
+    return its directory (`compile_cache_dir`).  Call it once from an
+    entry point, before the first compile.
 
-    The min-size/min-compile-time floors are dropped to zero because
-    this workload is many medium-sized programs (fused day queries,
-    fleet scans), none of which clear jax's default 1 s floor despite
-    dominating cold start.  Cache config APIs moved across jax
-    releases; failures degrade to uncached compiles, never to errors.
-    """
-    global _CACHE_ENABLED
-    if os.environ.get("REPRO_COMPILE_CACHE", "1") == "0":
-        return None
-    if _CACHE_ENABLED is not None:
-        return _CACHE_ENABLED
+    With ``JAX_COMPILATION_CACHE_DIR`` set, jax already reads that
+    directory and no other is configured here.  The min-size /
+    min-compile-time floors drop to zero because this workload is many
+    medium-sized programs (fused day queries, fleet scans), none of
+    which clears jax's default 1 s floor although together they
+    dominate cold start.  jax's own ``jax_enable_compilation_cache``
+    switch still turns the cache off."""
     cache_dir = compile_cache_dir()
-    cache_dir.mkdir(parents=True, exist_ok=True)
-    try:
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir.mkdir(parents=True, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-    except (AttributeError, ValueError):   # older/newer flag spellings
-        try:
-            from jax.experimental.compilation_cache import \
-                compilation_cache as _cc
-            _cc.set_cache_dir(str(cache_dir))
-        except Exception:
-            return None
-    _CACHE_ENABLED = cache_dir
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return cache_dir
 
 
 def shard_map(*args, **kwargs):
-    """jax.shard_map moved out of jax.experimental only in newer jax;
-    the replication-check kwarg was also renamed check_rep -> check_vma."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(*args, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm(*args, **kwargs)
+    """`jax.shard_map` (kwargs as jax spells them, e.g. ``check_vma``)."""
+    return jax.shard_map(*args, **kwargs)
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit-Auto axis types where supported.
-
-    `jax.sharding.AxisType` only exists in newer jax; older versions
-    default every axis to Auto, so omitting the argument is equivalent.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis explicitly Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

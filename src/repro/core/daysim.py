@@ -1496,7 +1496,7 @@ def _build_fused(plats: tuple, backend: str):
     untouched, which is the zero-retrace contract the twin tests pin."""
     stages = [_row_stage(p) for p in plats]
     if backend == "pallas":
-        from ..kernels.day_scan import day_scan
+        from ..kernels.ops import day_scan
     elif backend != "xla":
         raise ValueError(f"unknown backend {backend!r}; "
                          f"expected 'xla' or 'pallas'")
@@ -1550,11 +1550,13 @@ def _build_fused_batch(plats: tuple, backend: str):
     """The fused body vmapped over a leading query axis: K value-level
     what-ifs (stacked `dyn` / `ix` pytrees) evaluate through ONE jitted
     program.  The inner body is `_build_fused`'s — same ops, vmapped —
-    so each lane's objectives, survival flags and front mask are
-    bit-identical to the serial single-query program's (parity-pinned
-    in tests/test_twin_serving.py), and the trace counter inside it
-    bumps once per batch-shape trace, keeping the zero-retrace
-    contract observable for batched serving too."""
+    so on the CPU each lane's objectives, survival flags and front mask
+    are bit-identical to the serial single-query program's
+    (parity-pinned in tests/test_twin_serving.py; on a TPU v5e the
+    batch program's peaks and pod-hours differ in the last float32
+    bits), and the trace counter inside it bumps once per batch-shape
+    trace, keeping the zero-retrace contract observable for batched
+    serving too."""
     fused = _build_fused(plats, backend)
 
     def fused_batch(dyn, ix):

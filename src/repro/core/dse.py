@@ -492,8 +492,9 @@ def day_pareto_batch(queries, **shared):
     same bucketed shape signature (same platforms / schedule lengths /
     combo buckets — value-level deltas only), which is what
     `serving.twin.DesignTwin.query_batch` micro-batches by.  Returns
-    one `DayReport` per query, `front_mask` filled, each bit-identical
-    to the serial `day_pareto` answer for the same kwargs."""
+    one `DayReport` per query, `front_mask` filled, each the serial
+    `day_pareto` answer for the same kwargs (bit-identical on the CPU
+    backend)."""
     from . import daysim
     return daysim.day_grid_batch(list(queries), **shared)
 

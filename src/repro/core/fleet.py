@@ -22,7 +22,7 @@ user's day through ONE `jax.lax.scan`:
     `daysim._step_math` battery/thermal/throttle dynamics (vmapped
     across users), so fleet dynamics are bit-compatible with the
     single-device integrator;
-  * users are sharded across devices with `repro.compat.shard_map` over
+  * users are sharded across devices with `jax.shard_map` over
     a `make_mesh(("users",))` mesh — a single-device mesh is the
     CPU-CI fallback and runs the identical code path.
 
@@ -413,6 +413,35 @@ def _bin_tables(spec: PopulationSpec, pop: Population, dt_s: float,
 # the fleet scan: whole-population state through daysim._step_math
 # ---------------------------------------------------------------------------
 
+# users per block of the per-step bin sums: the population is padded to
+# a multiple of (shards x block), so every shard count sees the same
+# blocks of the same users
+_USER_BLOCK = 32
+
+
+def _bin_sums(x, ubins, n_bins: int):
+    """(N, S) per-user values -> (n_bins, S) sums per UTC bin.
+
+    A scatter-add over all N users adds a bin's users one after
+    another: its float32 error grows with N and, the users' values
+    being alike, does not cancel.  At 100,000 users that biased the
+    curve by ~1e-5 relative, differently for every shard count.  Here
+    the scatter-add stays inside blocks of `_USER_BLOCK` consecutive
+    users, and the block sums are added as a balanced binary tree,
+    whose error grows with log2(N / _USER_BLOCK)."""
+    n_blocks = x.shape[0] // _USER_BLOCK
+    seg = jnp.arange(x.shape[0]) // _USER_BLOCK * n_bins + ubins
+    x = jax.ops.segment_sum(x, seg, num_segments=n_blocks * n_bins)
+    x = x.reshape(n_blocks, n_bins, -1)
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        y = x[:h] + x[h:2 * h]
+        if x.shape[0] % 2:
+            y = y.at[0].add(x[2 * h])
+        x = y
+    return x[0]
+
+
 def _kahan_add(total, comp, inc):
     """One compensated-summation step: float32 accumulators across
     thousands of scan steps would otherwise drift past the 1e-6 parity
@@ -470,10 +499,9 @@ def _integrate_fleet(user: dict, const_u: dict, xs: dict,
         aa = (out["act"] * out["alive"])[:, None] * user["w"][:, None]
         pods_stream = aa * ps
         ubins = x["bins"][user["joff"]]
-        binc = jax.ops.segment_sum(pods_stream, ubins,
-                                   num_segments=n_bins)
+        binc = _bin_sums(pods_stream, ubins, n_bins)
         live = aa * (ps > 0.0)          # streams concurrently active
-        sbinc = jax.ops.segment_sum(live, ubins, num_segments=n_bins)
+        sbinc = _bin_sums(live, ubins, n_bins)
         curve, curve_c = _kahan_add(acc["curve"], acc["curve_c"], binc)
         streams, streams_c = _kahan_add(acc["streams"],
                                         acc["streams_c"], sbinc)
@@ -539,18 +567,19 @@ def _fleet_runner(n_shards: int, n_bins: int, n_days: int = 1):
                                             n_days)
         return per_user, jax.lax.psum(curves, "users")
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         run_psum, mesh=mesh,
         in_specs=(P("users"), P("users"), P()),
         out_specs=(P("users"), P()), check_vma=False))
 
 
 def _pad_users(arrs: dict, n_shards: int) -> tuple:
-    """Pad every (N, ...) leaf to a multiple of the mesh size with
-    zero-weight clones of user 0 (they integrate but contribute nothing
-    to the curve, and their rows are sliced off afterwards)."""
+    """Pad every (N, ...) leaf to a multiple of the mesh size times
+    `_USER_BLOCK` with zero-weight clones of user 0 (they integrate but
+    contribute nothing to the curve, and their rows are sliced off
+    afterwards)."""
     n = arrs["arch"].shape[0]
-    pad = (-n) % n_shards
+    pad = (-n) % (n_shards * _USER_BLOCK)
     if pad == 0:
         return arrs, n
     out = {k: np.concatenate([v, np.repeat(v[:1], pad, 0)])
@@ -592,6 +621,8 @@ class FleetReport:
     n_shards: int = 1
     stream_curve: np.ndarray | None = None   # (n_bins, S)
     n_days: int = 1
+    user_devices: int = 1           # devices the per-user outputs
+                                    # were sharded over
 
     def __len__(self) -> int:
         return len(self.population)
@@ -853,6 +884,7 @@ def fleet_day(population, n_users: int | None = None, key=0, *,
         run(jax.tree_util.tree_map(jnp.asarray, user_p),
             jax.tree_util.tree_map(jnp.asarray, const_p),
             prep.xs_dev))
+    user_devices = len(per_user["first"].sharding.device_set)
     per_user = {k: np.asarray(v)[:n] for k, v in per_user.items()}
     # the scan accumulates raw per-step pod counts; one step covers
     # dt_s of wall time, so normalizing by (step hours / bin hours)
@@ -877,7 +909,8 @@ def fleet_day(population, n_users: int | None = None, key=0, *,
         shutdown=per_user["shut"] > 0.5,
         pod_hours=per_user["pod_steps"].astype(np.float64) * h,
         skin_limit_c=skin_limit_c, n_shards=n_shards,
-        stream_curve=stream_curve * scale, n_days=n_days)
+        stream_curve=stream_curve * scale, n_days=n_days,
+        user_devices=user_devices)
 
 
 def reference_fleet(pop: Population, *, dt_s: float = 60.0,

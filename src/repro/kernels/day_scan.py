@@ -19,11 +19,12 @@ values.
 
 `day_scan(tables)` accepts the same batched table pytree the vmapped
 scan consumes ((N, T, L) level tables, (N, T) step rows, (N,) consts)
-and returns the output subset the day summarizer needs.  On CPU (tests,
-CI) the kernel runs in interpret mode automatically; `day_scan_ref` is
-the `_integrate_one` oracle restricted to the same outputs — parity is
-asserted at 1e-6 in tests/test_kernels.py, throttling and puck-split
-combos included.
+and returns the output subset the day summarizer needs.  Through
+`kernels.ops` it runs in interpret mode on the CPU (tests) and compiled
+everywhere else (tests/test_tpu_compile.py compiles it for a v5e);
+`day_scan_ref` is the `_integrate_one` oracle restricted to the same
+outputs — parity is asserted at 1e-6 in tests/test_kernels.py,
+throttling and puck-split combos included.
 """
 from __future__ import annotations
 
@@ -63,11 +64,10 @@ def _day_kernel(mw_ref, mwp_ref, pods_ref, amult_ref, amb_ref, act_ref,
                                  zero, zero, zero)):
             state[row, :] = v
 
-    mw = mw_ref[...]                    # (chunk, L, LANES)
-    mwp = mwp_ref[...]
-    pods_t = pods_ref[...]
     amult = amult_ref[...]              # (L, LANES)
-    lvls = jax.lax.broadcasted_iota(jnp.float32, (n_lvl, LANES), 0)
+    # Mosaic builds integer iotas only
+    lvls = jax.lax.broadcasted_iota(jnp.int32, (n_lvl, LANES),
+                                    0).astype(jnp.float32)
 
     def take(tab, level):
         """Hat-weight level gather — exact at integer levels."""
@@ -116,9 +116,9 @@ def _day_kernel(mw_ref, mwp_ref, pods_ref, amult_ref, amb_ref, act_ref,
                  * jnp.where(soc_p > 0.0, 1.0, 0.0)
                  * (1.0 - shut) * val_ref[i, :])
         act = act_ref[i, :] * take(amult, level)
-        p_mw = (act * take(mw[i], level)
+        p_mw = (act * take(mw_ref[i], level)
                 + (1.0 - act) * c("standby_mw")) * alive
-        p_p_mw = (act * take(mwp[i], level)
+        p_p_mw = (act * take(mwp_ref[i], level)
                   + (1.0 - act) * c("p_standby_mw")) * alive \
             * c("has_puck")
 
@@ -134,7 +134,7 @@ def _day_kernel(mw_ref, mwp_ref, pods_ref, amult_ref, amb_ref, act_ref,
         tskinp_o[i, :] = t_skin_p
         shut_o[i, :] = shut
         level_o[i, :] = level
-        pods_o[i, :] = act * take(pods_t[i], level) * alive
+        pods_o[i, :] = act * take(pods_ref[i], level) * alive
         drain_o[i, :] = drain_mw
         drainp_o[i, :] = drain_p_mw
         return (soc, soc_p, t_soc, t_skin, t_soc_p, t_skin_p,
@@ -146,17 +146,14 @@ def _day_kernel(mw_ref, mwp_ref, pods_ref, amult_ref, amb_ref, act_ref,
         state[row, :] = v
 
 
-def day_scan(tables: dict, *, chunk: int = 128,
-             interpret: bool | None = None) -> dict:
+def day_scan(tables: dict, *, chunk: int = 128, interpret: bool) -> dict:
     """Integrate the batched day tables through the fused Pallas step.
 
     `tables` is the `daysim.batch_tables`-shaped pytree ((N, T, L) level
     tables, (N, T) step rows, (N, L) act_mult, const dict of (N,)
     scalars).  Returns {out: (N, T)} for `OUTS` (level as int32),
     matching `day_scan_ref` / the vmapped `_integrate_one` outputs.
-    `interpret=None` auto-enables interpret mode off-TPU (CPU CI)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    `kernels.ops.day_scan` picks `interpret` for the running backend."""
     mw = jnp.asarray(tables["step_mw"], jnp.float32)
     n, t, n_lvl = mw.shape
     nb = -(-n // LANES)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this records, into results/dryrun/<arch>__<shape>__<mesh>.json:
@@ -28,7 +25,7 @@ from ..configs.base import SHAPES, shape_applicable
 from ..models import registry
 from . import steps as steps_lib
 from .hlo_analysis import analyze_hlo
-from .mesh import make_production_mesh
+from .mesh import make_production_mesh, pin_host_platform
 
 # TPU v5e-class hardware constants (per chip) — source of truth lives in
 # sweep.py (importable without jax); re-exported here for the compiled path
@@ -194,6 +191,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: Path,
 
 
 def main():
+    pin_host_platform()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -8,7 +8,22 @@ only gradient all-reduce crosses the (slow) pod interconnect.
 """
 from __future__ import annotations
 
+import jax
+
 from ..compat import make_mesh as compat_make_mesh
+
+# virtual CPU devices the compile-only dry-run lowers the 512-chip
+# production mesh onto
+HOST_DEVICES = 512
+
+
+def pin_host_platform() -> None:
+    """Pin this process to `HOST_DEVICES` virtual CPU devices, never an
+    attached accelerator.  For the compile-only entry points (dry-run,
+    perf variants, sweep workers); call it before the process's first
+    JAX computation."""
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", HOST_DEVICES)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

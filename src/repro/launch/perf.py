@@ -1,5 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """SSPerf hillclimb harness: re-lower one cell with config-variant knobs and
 re-derive the roofline terms (hypothesis -> change -> measure -> validate).
 
@@ -27,7 +25,7 @@ from ..models import registry
 from . import steps as steps_lib
 from .dryrun import PEAK_FLOPS, HBM_BW, ICI_BW, memory_stats, model_flops
 from .hlo_analysis import analyze_hlo
-from .mesh import make_production_mesh
+from .mesh import make_production_mesh, pin_host_platform
 
 
 def apply_variant(cfg, overrides: dict):
@@ -97,6 +95,7 @@ VARIANTS = {
 
 
 def main():
+    pin_host_platform()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

@@ -9,9 +9,10 @@ meshes = 80 cells), mirroring the device-side DSE batching pattern
   worker processes.  Resumable: cells whose artifact already parses as
   ok/skipped are never redone; failed or corrupt artifacts are retried
   (disable with ``retry_failed=False``).  Workers are spawned (never
-  forked) so each initialises jax fresh with
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` — the parent's
-  jax state (if any) cannot leak a wrong device count into a compile.
+  forked) so each initialises jax fresh, pinned to 512 virtual CPU
+  devices (`mesh.pin_host_platform`) — the parent's jax state (if any)
+  cannot leak a wrong device count into a compile, and no worker opens
+  an attached accelerator.
 
 * ``CellTable`` / ``analytical_terms`` — a struct-of-arrays ANALYTICAL
   roofline: first-order FLOPs / HBM / collective terms for every cell in
@@ -105,10 +106,10 @@ def pending_cells(cells=None, out_dir=RESULTS,
 
 
 def _worker_init():
-    # MUST precede the first jax import in the spawned worker: jax locks
-    # the host device count on first init (same contract as dryrun.py).
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    # MUST precede the worker's first jax computation: jax fixes the
+    # platform and the host device count when its backend starts
+    from .mesh import pin_host_platform
+    pin_host_platform()
 
 
 def _worker_cell(cell: tuple, out_dir: str, force: bool) -> str:

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map as _compat_shard_map
 from . import core
 
 NEG_INF = -1e30  # large-but-finite; avoids NaN from (-inf) - (-inf)
@@ -341,9 +340,9 @@ def sharded_decode_attention(mesh, q, k, v, cur_len, *, kv_axes=("model",),
         def local(q_, k_, v_, cur_):
             return attend(q_, k_, v_, cur_, shard_off())
 
-        fn = _compat_shard_map(local, mesh=mesh,
-                           in_specs=(q_spec, kv_spec, kv_spec, P()),
-                           out_specs=q_spec, check_vma=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(q_spec, kv_spec, kv_spec, P()),
+                       out_specs=q_spec, check_vma=False)
         return fn(q, k, v, cur_len)
 
     def local_upd(q_, k_, v_, kn_, vn_, cur_, valid_):
@@ -361,7 +360,7 @@ def sharded_decode_attention(mesh, q, k, v, cur_len, *, kv_axes=("model",),
 
     if valid_len is None:
         valid_len = cur_len + 1
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         local_upd, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, new_spec, new_spec, P(), P()),
         out_specs=(q_spec, kv_spec, kv_spec), check_vma=False)

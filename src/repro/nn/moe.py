@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map as _compat_shard_map
 from . import core
 
 
@@ -165,7 +164,7 @@ def moe_apply_sharded(params: core.Params, x: jnp.ndarray, *, mesh,
         aux = jax.lax.pmean(aux, baxes) if baxes else aux
         return y, aux
 
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec, w_spec, wo_spec),
         out_specs=(x_spec, P()),

@@ -151,47 +151,27 @@ def asr_conformer(key, mel):
 # measured FLOPs per invocation
 # --------------------------------------------------------------------------
 
-_FLOPS_NETS = ("hand_tracker", "eye_tracker", "vio_imu", "vio_frontend",
-               "vad", "asr_1s")
-
-
-def _flops_cache_file():
-    """Disk cache for the measured-FLOPs table, next to the persistent
-    compile cache (same version key, same opt-out).  Lowering all six
-    nets costs ~3 s per fresh process and the result is a pure
-    function of (net definitions, jax version), so a tiny JSON beats
-    re-deriving it on every restart."""
-    from .. import compat
-    import os
-    if os.environ.get("REPRO_COMPILE_CACHE", "1") == "0":
-        return None
-    return compat.compile_cache_dir() / "measured_flops.json"
-
-
 @functools.lru_cache(maxsize=None)
 def measured_flops() -> dict[str, float]:
-    """Compiled-FLOPs per single invocation of each primitive net."""
-    import json
-    cache = _flops_cache_file()
-    if cache is not None and cache.exists():
-        try:
-            out = json.loads(cache.read_text())
-            if set(out) == set(_FLOPS_NETS):
-                return {k: float(v) for k, v in out.items()}
-        except (json.JSONDecodeError, TypeError, ValueError):
-            pass                              # corrupt cache: re-derive
-    key = jax.random.PRNGKey(0)
+    """Compiled-FLOPs per single invocation of each primitive net.
+
+    Always derived on the host CPU backend, whatever accelerator the
+    process holds: the wearable model must not depend on the device
+    that runs the simulator (a TPU compile counts 12-61% more FLOPs
+    for the same nets), and `core/calibrated.json` was fit against the
+    CPU counts."""
+    cpu = jax.devices("cpu")[0]
+    on_cpu = jax.sharding.SingleDeviceSharding(cpu)
+    with jax.default_device(cpu):
+        key = jax.random.PRNGKey(0)
 
     def flops(fn, *shapes):
-        args = [jnp.zeros(s, jnp.float32) for s in shapes]
+        args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=on_cpu)
+                for s in shapes]
         c = jax.jit(lambda *a: fn(key, *a)).lower(*args).compile()
-        ca = c.cost_analysis()
-        # jax returns either a dict or a per-device list of dicts
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        return float((ca or {}).get("flops", 0.0))
+        return float(c.cost_analysis()["flops"])
 
-    out = {
+    return {
         "hand_tracker": flops(hand_tracker, (1, 2, 128, 128, 1)),
         "eye_tracker": flops(eye_tracker, (1, 2, 96, 96, 1)),
         "vio_imu": flops(vio_imu_net, (1, 200, 6)),
@@ -199,10 +179,3 @@ def measured_flops() -> dict[str, float]:
         "vad": flops(vad, (1, 100, 40)),
         "asr_1s": flops(asr_conformer, (1, 100, 80)),
     }
-    if cache is not None:
-        try:
-            cache.parent.mkdir(parents=True, exist_ok=True)
-            cache.write_text(json.dumps(out, indent=1))
-        except OSError:
-            pass                              # read-only checkout: skip
-    return out

@@ -21,16 +21,17 @@ Three serving-stack mechanisms keep that latency flat under load:
 * **Batched queries** — `query_batch()` / `what_if_many()` stack K
   value-level what-ifs along a leading query axis and evaluate them
   through ONE jitted program (`dse.day_pareto_batch`, a `jax.vmap` of
-  the single-query body, so results are bit-identical to serial
-  queries).  `submit()`/`run()` micro-batch the admission queue up to
-  `batch_window` items, grouping by bucketed shape signature and
-  fanning results back out in order.
+  the single-query body: results are bit-identical to serial queries
+  on the CPU; on a TPU the batch program rounds peaks and pod-hours
+  differently in the last float32 bits).  `submit()`/`run()`
+  micro-batch the admission queue up to `batch_window` items, grouping
+  by bucketed shape signature and fanning results back out in order.
 * **Persistent compilation cache** — construction calls
-  `compat.enable_persistent_cache()`, pointing jax's compilation cache
-  at ``results/compile_cache/jax-<version>/`` so a process restart
-  deserializes the fused executables from disk (~19 s cold first
-  query -> ~1 s).  Opt out with ``REPRO_COMPILE_CACHE=0``; relocate
-  with ``REPRO_COMPILE_CACHE_DIR=<dir>``.
+  `compat.enable_persistent_cache()`, which keeps jax's compilation
+  cache in ``JAX_COMPILATION_CACHE_DIR`` when that is set and in
+  ``results/compile_cache/jax-<version>/`` otherwise, so a process
+  restart deserializes the fused executables from disk instead of
+  compiling them again.
 
 `query(**grid_overrides)` runs one full grid and returns the DayReport
 with the front attached; `what_if(design=..., policy=...)` is the
@@ -147,8 +148,8 @@ class DesignTwin:
         `shared` and the base grid).  Queries are grouped by bucketed
         shape signature — each group runs as ONE `dse.day_pareto_batch`
         program with a leading query axis — and the reports come back
-        in submission order, each bit-identical to the serial
-        `query(**q)` answer."""
+        in submission order, each the serial `query(**q)` answer
+        (bit-identical on the CPU backend)."""
         args = dict(self.base)
         args.update(shared)
         backend = args.pop("backend", "xla")

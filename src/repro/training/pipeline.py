@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map as _compat_shard_map
-
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
     return (n_stages - 1) / (n_micro + n_stages - 1)
@@ -82,7 +80,7 @@ def pipeline_apply(layer_fn: Callable, stage_params, x_micro, *, mesh,
             stage_axis)
         return outs
 
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),

@@ -21,7 +21,7 @@ pop = fleet.sample_population(fleet.DEFAULT_POPULATION, 11, key=3)
 r1 = fleet.fleet_day(pop, dt_s=120.0, n_shards=1)
 for n_shards in (2, 4):
     rs = fleet.fleet_day(pop, dt_s=120.0, n_shards=n_shards)
-    assert rs.n_shards == n_shards
+    assert rs.n_shards == rs.user_devices == n_shards
     assert np.array_equal(r1.time_to_empty_h, rs.time_to_empty_h)
     assert np.array_equal(r1.survives(), rs.survives())
     assert np.array_equal(r1.shutdown, rs.shutdown)

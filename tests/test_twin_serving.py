@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -251,44 +252,43 @@ def test_cache_stats_accessor(twin):
 
 
 def test_persistent_cache_shim(monkeypatch, tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR`, when set, is the cache directory and
+    no other is configured in code; unset, the cache lives at the fixed
+    version-keyed path inside the checkout."""
     import jax
-    prev_enabled = compat._CACHE_ENABLED
     prev_dir = jax.config.jax_compilation_cache_dir
     try:
-        monkeypatch.setenv("REPRO_COMPILE_CACHE", "0")
-        compat._CACHE_ENABLED = None
-        assert compat.enable_persistent_cache() is None
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compat.compile_cache_dir() == tmp_path
+        assert compat.enable_persistent_cache() == tmp_path
+        assert jax.config.jax_compilation_cache_dir == prev_dir
 
-        monkeypatch.setenv("REPRO_COMPILE_CACHE", "1")
-        monkeypatch.setenv("REPRO_COMPILE_CACHE_DIR", str(tmp_path))
-        compat._CACHE_ENABLED = None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         out = compat.enable_persistent_cache()
-        assert out == tmp_path / f"jax-{jax.__version__}"
+        assert out == (Path(__file__).resolve().parents[1] / "results"
+                       / "compile_cache" / f"jax-{jax.__version__}")
         assert out.is_dir()
         assert jax.config.jax_compilation_cache_dir == str(out)
-        # idempotent: second call returns the same dir without rework
+        # idempotent: a second call configures the same directory
         assert compat.enable_persistent_cache() == out
     finally:
-        compat._CACHE_ENABLED = prev_enabled
         if prev_dir is not None:
             jax.config.update("jax_compilation_cache_dir", prev_dir)
 
 
 def test_measured_flops_disk_cache(monkeypatch, tmp_path):
+    """The measured-FLOPs table is derived on the host CPU backend and
+    neither read from nor written to any cache directory: a stale table
+    lying in the compile cache cannot change the wearable model."""
     import json
     from repro.perception import nets
-    monkeypatch.setenv("REPRO_COMPILE_CACHE", "0")
-    assert nets._flops_cache_file() is None
-    monkeypatch.setenv("REPRO_COMPILE_CACHE", "1")
-    monkeypatch.setenv("REPRO_COMPILE_CACHE_DIR", str(tmp_path))
-    f = nets._flops_cache_file()
-    assert f.parent.parent == tmp_path
-    # a cached table with the right keys is served verbatim, no lowering
-    f.parent.mkdir(parents=True, exist_ok=True)
-    fake = {k: float(i + 1) for i, k in enumerate(nets._FLOPS_NETS)}
-    f.write_text(json.dumps(fake))
+    want = nets.measured_flops()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    fake = {k: float(i + 1) for i, k in enumerate(want)}
+    (tmp_path / "measured_flops.json").write_text(json.dumps(fake))
     nets.measured_flops.cache_clear()
     try:
-        assert nets.measured_flops() == fake
+        assert nets.measured_flops() == want
     finally:
         nets.measured_flops.cache_clear()
+    assert [p.name for p in tmp_path.iterdir()] == ["measured_flops.json"]
