@@ -49,7 +49,8 @@ for wi in twin.run():
     r = wi.report
     print(f"{wi.overrides['battery'].name:9s}: "
           f"{int(r.survives().sum()):2d}/{len(r)} survive, "
-          f"front {int(r.front_mask.sum())}, {wi.ms:6.1f} ms")
+          f"front {int(r.front_mask.sum())}, "
+          f"{wi.ms:6.1f} ms from submit to answer")
 
 st = twin.stats
 print(f"\n{st.queries} queries in {st.batches} batched executions: "
@@ -57,9 +58,15 @@ print(f"\n{st.queries} queries in {st.batches} batched executions: "
       f"mean {st.mean_ms:.0f} ms")
 
 # every daysim cache tier in one snapshot: scenario-row tables, host
-# assemblies, value-keyed pipelines, compiled executables
-for tier, s in daysim.cache_stats().items():
+# assemblies, value-keyed pipelines, compiled executables; then the host
+# phases' counters
+stats = daysim.cache_stats()
+host_phases = stats.pop("phases")
+for tier, s in stats.items():
     extras = "".join(f", {k}={s[k]}" for k in ("evictions", "traces")
                      if k in s)
     print(f"cache[{tier}]: {s['hits']} hits / {s['misses']} misses, "
           f"{s['size']} live{extras}")
+for name, s in sorted(host_phases.items()):
+    print(f"phase[{name}]: {s['calls']} calls, "
+          f"{s['total_ns'] / 1e6:.1f} ms")

@@ -66,7 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import design, offload, scenarios
+from . import design, offload, phases, scenarios
 from .design import ste_gt, ste_lt, take_linear
 from .platform import PlatformSpec
 from .scenarios import DEFAULT_MCS, ScenarioSet
@@ -908,12 +908,16 @@ def cache_stats() -> dict:
     assembled-pipeline cache, and ``exec`` the signature-keyed compiled
     executable cache (whose ``traces`` counter the zero-retrace tests
     pin).  The FIFO tiers evict silently during queries; this accessor
-    is how benchmarks and `examples/what_if.py` make that visible."""
+    is how benchmarks and `examples/what_if.py` make that visible.
+    ``phases`` is a copy of `phases.PHASE_STATS`: the host phases' call
+    counts, times and duration histograms (`clear_exec_cache` leaves
+    them as they are)."""
     return {
         "rows": {**CACHE_STATS, "size": len(_ROW_CACHE)},
         "assemblies": {**ASSEMBLY_STATS, "size": len(_ASSEMBLIES)},
         "pipelines": {**PIPELINE_STATS, "size": len(_PIPELINES)},
         "exec": {**EXEC_STATS, "size": len(_EXEC_CACHE)},
+        "phases": phases.snapshot(),
     }
 
 
@@ -1346,27 +1350,29 @@ def _enumerate_combos(platforms, designs, schedules, policies,
     device pipeline (which never does).  Designs whose placement a
     platform cannot run on-device are skipped, mirroring the engine's
     placement check."""
-    schedules = [_resolve(s, get_schedule, DaySchedule)
-                 for s in schedules]
-    policies = [_resolve(p, get_policy, ThrottlePolicy) for p in policies]
-    therm = thermal or DEFAULT_THERMAL
-    groups, skipped = [], []
-    for p in platforms:
-        plat = _plat(p)
-        supported = set(plat.supported_primitives())
-        bat = _batteries_arg(battery, plat.name)
-        puck = puck_for(plat)
-        plat_combos = []
-        for d in designs:
-            if not set(d["on_device"]) <= supported:
-                skipped.append({"platform": plat.name,
-                                "design": d.get("name", ""),
-                                "reason": "unsupported placement"})
-                continue
-            plat_combos.extend(
-                _Combo(plat, d, sched, pol, bat, therm, puck)
-                for sched in schedules for pol in policies)
-        groups.append((plat, plat_combos))
+    with phases.phase("daysim.enumerate"):
+        schedules = [_resolve(s, get_schedule, DaySchedule)
+                     for s in schedules]
+        policies = [_resolve(p, get_policy, ThrottlePolicy)
+                    for p in policies]
+        therm = thermal or DEFAULT_THERMAL
+        groups, skipped = [], []
+        for p in platforms:
+            plat = _plat(p)
+            supported = set(plat.supported_primitives())
+            bat = _batteries_arg(battery, plat.name)
+            puck = puck_for(plat)
+            plat_combos = []
+            for d in designs:
+                if not set(d["on_device"]) <= supported:
+                    skipped.append({"platform": plat.name,
+                                    "design": d.get("name", ""),
+                                    "reason": "unsupported placement"})
+                    continue
+                plat_combos.extend(
+                    _Combo(plat, d, sched, pol, bat, therm, puck)
+                    for sched in schedules for pol in policies)
+            groups.append((plat, plat_combos))
     return groups, skipped
 
 
@@ -1493,7 +1499,10 @@ def _build_fused(plats: tuple, backend: str):
     objectives, and extracts the non-dominated front — tables never
     visit the host.  `EXEC_STATS["traces"]` is bumped by the Python
     body, i.e. at trace time only: warm same-shaped queries leave it
-    untouched, which is the zero-retrace contract the twin tests pin."""
+    untouched, which is the zero-retrace contract the twin tests pin.
+    The five stages run under `jax.named_scope`s (`row_stage`, `gather`,
+    `day_scan`, `summary`, `front`), which reach each op's `op_name`
+    metadata, so a device profile can attribute the program's time."""
     stages = [_row_stage(p) for p in plats]
     if backend == "pallas":
         from ..kernels.ops import day_scan
@@ -1505,42 +1514,49 @@ def _build_fused(plats: tuple, backend: str):
         # repro: ignore[R002]: trace-counter by design — it MUST run at
         # trace time only; the zero-retrace tests assert it stays flat
         EXEC_STATS["traces"] += 1
-        outs = []
-        for stage, g in zip(stages, dyn["groups"]):
-            total, mbps, mw_p, pods, _ = stage(
-                g["vec"], g["theta"], dyn["rates"], dyn["gate"],
-                g["p_base"], g["p_wan"])
-            outs.append((total, mw_p, pods))
-        total = jnp.concatenate([o[0] for o in outs])
-        mw_p = jnp.concatenate([o[1] for o in outs])
-        pods = jnp.concatenate([o[2] for o in outs])
-        # (N, T, L) row gather: combo row base + level stride + segment
-        rows_ntl = ix["lvl_row"][:, None, :] + ix["seg_of"][:, :, None]
-        tables = {"step_mw": total[rows_ntl],
-                  "step_mw_p": mw_p[rows_ntl],
-                  "step_pods": pods[rows_ntl],
-                  "act_mult": dyn["act_mult"],
-                  "ambient": ix["ambient"], "active": ix["active"],
-                  "valid": ix["valid"], "charge": ix["charge"],
-                  "charge_p": ix["charge_p"], "const": dyn["const"]}
-        if backend == "pallas":
-            ys = day_scan(tables)
-        else:
-            ys = jax.vmap(_integrate_one)(tables)
-        summ = _summarize_jax(ys, ix["valid"], ix["active"], dyn["dt_s"])
-        summ["steady_mw"] = total[ix["steady_of"]]
+        with jax.named_scope("row_stage"):
+            outs = []
+            for stage, g in zip(stages, dyn["groups"]):
+                total, mbps, mw_p, pods, _ = stage(
+                    g["vec"], g["theta"], dyn["rates"], dyn["gate"],
+                    g["p_base"], g["p_wan"])
+                outs.append((total, mw_p, pods))
+            total = jnp.concatenate([o[0] for o in outs])
+            mw_p = jnp.concatenate([o[1] for o in outs])
+            pods = jnp.concatenate([o[2] for o in outs])
+        with jax.named_scope("gather"):
+            # (N, T, L) row gather: combo row base + level stride + segment
+            rows_ntl = ix["lvl_row"][:, None, :] + ix["seg_of"][:, :, None]
+            tables = {"step_mw": total[rows_ntl],
+                      "step_mw_p": mw_p[rows_ntl],
+                      "step_pods": pods[rows_ntl],
+                      "act_mult": dyn["act_mult"],
+                      "ambient": ix["ambient"], "active": ix["active"],
+                      "valid": ix["valid"], "charge": ix["charge"],
+                      "charge_p": ix["charge_p"], "const": dyn["const"]}
+        with jax.named_scope("day_scan"):
+            if backend == "pallas":
+                ys = day_scan(tables)
+            else:
+                ys = jax.vmap(_integrate_one)(tables)
+        with jax.named_scope("summary"):
+            summ = _summarize_jax(ys, ix["valid"], ix["active"],
+                                  dyn["dt_s"])
+            summ["steady_mw"] = total[ix["steady_of"]]
         from . import dse
-        obj = jnp.stack([summ["time_to_empty_h"], summ["peak_skin_c"],
-                         summ["pod_hours"]], axis=1)
-        # bucket padding: zero-weight clone lanes are forced to the
-        # worst corner (tte -inf maximized; peak/pods +inf minimized),
-        # so every real row strictly dominates them and the real rows'
-        # front mask is bit-identical to the unpadded grid's
-        w = dyn["combo_w"] > 0.0
-        obj = jnp.where(w[:, None],
-                        obj, jnp.asarray([-jnp.inf, jnp.inf, jnp.inf],
-                                         obj.dtype))
-        summ["front_mask"] = dse.non_dominated_jax(obj, maximize=(0,)) & w
+        with jax.named_scope("front"):
+            obj = jnp.stack([summ["time_to_empty_h"], summ["peak_skin_c"],
+                             summ["pod_hours"]], axis=1)
+            # bucket padding: zero-weight clone lanes are forced to the
+            # worst corner (tte -inf maximized; peak/pods +inf minimized),
+            # so every real row strictly dominates them and the real
+            # rows' front mask is bit-identical to the unpadded grid's
+            w = dyn["combo_w"] > 0.0
+            obj = jnp.where(w[:, None],
+                            obj, jnp.asarray([-jnp.inf, jnp.inf, jnp.inf],
+                                             obj.dtype))
+            summ["front_mask"] = (dse.non_dominated_jax(obj, maximize=(0,))
+                                  & w)
         return summ
 
     return fused
@@ -1594,118 +1610,119 @@ def _assemble_query(platforms, designs, schedules, policies, dt_s,
         return asm
     ASSEMBLY_STATS["misses"] += 1
 
-    T = max(cb.schedule.n_steps(dt_s) for cb in combos)
-    L = max(cb.policy.n_levels for cb in combos)
-    rr = offload.stream_rates(results_dir)
-    grp_dyn, theta_keys, row_counts = [], [], []
-    lvl_row, seg_of, steady_of = [], [], []
-    ambs, acts, vals, chgs, chgs_p, amults, consts = \
-        [], [], [], [], [], [], []
-    base = 0
-    for plat, grp in groups:
-        rows, slices = [], []
-        for cb in grp:
-            slices.append(_combo_rows(cb, rows))
-        sset = ScenarioSet.build(rows, primitives=plat.primitives)
-        scenarios._validate(plat, sset)
-        r_b = bucket_size(len(rows)) if rows else 0
-        sset = sset.pad(r_b)
-        th = plat.theta_dict()
-        if theta:
-            th.update(theta)
-        p_base, p_wan = _puck_coeffs(plat)
-        grp_dyn.append({
-            "vec": {"placement": sset.placement,
-                    "compression": sset.compression,
-                    "fps_scale": sset.fps_scale,
-                    "mcs_tier": sset.mcs_tier,
-                    "upload_duty": sset.upload_duty,
-                    "brightness": sset.brightness},
-            "theta": {k: np.float32(v) for k, v in th.items()},
-            "p_base": np.float32(p_base), "p_wan": np.float32(p_wan)})
-        theta_keys.append(tuple(sorted(th)))
-        row_counts.append(r_b)
-        for cb, (start, steady_i) in zip(grp, slices):
-            segs = cb.schedule.segments
-            n_seg, n_lvl = len(segs), cb.policy.n_levels
-            seg_steps = [max(1, round(s.hours * 3600.0 / dt_s))
-                         for s in segs]
-            seg_idx = np.repeat(np.arange(n_seg), seg_steps)
-            t = len(seg_idx)
-            so = np.full(T, n_seg - 1, np.int32)   # pad: last segment
-            so[:t] = seg_idx
-            seg_of.append(so)
-            lv = np.minimum(np.arange(L), n_lvl - 1)  # pad: last level
-            lvl_row.append((base + start + lv * n_seg).astype(np.int32))
-            steady_of.append(base + steady_i)
-            amb = np.full(T, segs[-1].ambient_c, np.float32)
-            amb[:t] = np.asarray([s.ambient_c for s in segs],
-                                 np.float32)[seg_idx]
-            ambs.append(amb)
-            act = np.zeros(T, np.float32)
-            act[:t] = np.asarray([s.active for s in segs],
-                                 np.float32)[seg_idx]
-            acts.append(act)
-            val = np.zeros(T, np.float32)
-            val[:t] = 1.0
-            vals.append(val)
-            cap_g = cb.battery.capacity_mwh
-            cap_p = (cb.puck.battery.capacity_mwh
-                     if cb.puck is not None else 0.0)
-            share_g = cap_g / (cap_g + cap_p) if cap_p else 1.0
-            seg_charge = np.asarray([s.charge_mw for s in segs],
-                                    np.float32)[seg_idx]
-            chg = np.zeros(T, np.float32)
-            chg_p = np.zeros(T, np.float32)
-            chg[:t] = seg_charge * np.float32(share_g)
-            chg_p[:t] = seg_charge * np.float32(1.0 - share_g)
-            chgs.append(chg)
-            chgs_p.append(chg_p)
-            amult = np.ones(L, np.float32)
-            for l in range(1, n_lvl):
-                amult[l:] = cb.policy.action(l).active_mult
-            amults.append(amult)
-            consts.append(_combo_const(cb, dt_s, standby_mw, shutdown_c))
-        base += r_b
+    with phases.phase("daysim.assemble"):
+        T = max(cb.schedule.n_steps(dt_s) for cb in combos)
+        L = max(cb.policy.n_levels for cb in combos)
+        rr = offload.stream_rates(results_dir)
+        grp_dyn, theta_keys, row_counts = [], [], []
+        lvl_row, seg_of, steady_of = [], [], []
+        ambs, acts, vals, chgs, chgs_p, amults, consts = \
+            [], [], [], [], [], [], []
+        base = 0
+        for plat, grp in groups:
+            rows, slices = [], []
+            for cb in grp:
+                slices.append(_combo_rows(cb, rows))
+            sset = ScenarioSet.build(rows, primitives=plat.primitives)
+            scenarios._validate(plat, sset)
+            r_b = bucket_size(len(rows)) if rows else 0
+            sset = sset.pad(r_b)
+            th = plat.theta_dict()
+            if theta:
+                th.update(theta)
+            p_base, p_wan = _puck_coeffs(plat)
+            grp_dyn.append({
+                "vec": {"placement": sset.placement,
+                        "compression": sset.compression,
+                        "fps_scale": sset.fps_scale,
+                        "mcs_tier": sset.mcs_tier,
+                        "upload_duty": sset.upload_duty,
+                        "brightness": sset.brightness},
+                "theta": {k: np.float32(v) for k, v in th.items()},
+                "p_base": np.float32(p_base), "p_wan": np.float32(p_wan)})
+            theta_keys.append(tuple(sorted(th)))
+            row_counts.append(r_b)
+            for cb, (start, steady_i) in zip(grp, slices):
+                segs = cb.schedule.segments
+                n_seg, n_lvl = len(segs), cb.policy.n_levels
+                seg_steps = [max(1, round(s.hours * 3600.0 / dt_s))
+                             for s in segs]
+                seg_idx = np.repeat(np.arange(n_seg), seg_steps)
+                t = len(seg_idx)
+                so = np.full(T, n_seg - 1, np.int32)   # pad: last segment
+                so[:t] = seg_idx
+                seg_of.append(so)
+                lv = np.minimum(np.arange(L), n_lvl - 1)  # pad: last level
+                lvl_row.append((base + start + lv * n_seg).astype(np.int32))
+                steady_of.append(base + steady_i)
+                amb = np.full(T, segs[-1].ambient_c, np.float32)
+                amb[:t] = np.asarray([s.ambient_c for s in segs],
+                                     np.float32)[seg_idx]
+                ambs.append(amb)
+                act = np.zeros(T, np.float32)
+                act[:t] = np.asarray([s.active for s in segs],
+                                     np.float32)[seg_idx]
+                acts.append(act)
+                val = np.zeros(T, np.float32)
+                val[:t] = 1.0
+                vals.append(val)
+                cap_g = cb.battery.capacity_mwh
+                cap_p = (cb.puck.battery.capacity_mwh
+                         if cb.puck is not None else 0.0)
+                share_g = cap_g / (cap_g + cap_p) if cap_p else 1.0
+                seg_charge = np.asarray([s.charge_mw for s in segs],
+                                        np.float32)[seg_idx]
+                chg = np.zeros(T, np.float32)
+                chg_p = np.zeros(T, np.float32)
+                chg[:t] = seg_charge * np.float32(share_g)
+                chg_p[:t] = seg_charge * np.float32(1.0 - share_g)
+                chgs.append(chg)
+                chgs_p.append(chg_p)
+                amult = np.ones(L, np.float32)
+                for l in range(1, n_lvl):
+                    amult[l:] = cb.policy.action(l).active_mult
+                amults.append(amult)
+                consts.append(_combo_const(cb, dt_s, standby_mw, shutdown_c))
+            base += r_b
 
-    n_real = len(combos)
-    n_b = bucket_size(n_real)
+        n_real = len(combos)
+        n_b = bucket_size(n_real)
 
-    def _pad_n(a):
-        a = np.asarray(a)
-        if n_b == n_real:
-            return a
-        return np.concatenate([a, np.repeat(a[:1], n_b - n_real, 0)])
+        def _pad_n(a):
+            a = np.asarray(a)
+            if n_b == n_real:
+                return a
+            return np.concatenate([a, np.repeat(a[:1], n_b - n_real, 0)])
 
-    combo_w = np.zeros(n_b, np.float32)
-    combo_w[:n_real] = 1.0
-    dyn = {"groups": tuple(grp_dyn),
-           "rates": np.asarray(rr["tok_per_cap"], np.float32),
-           "gate": np.float32(n_users),
-           "act_mult": _pad_n(np.stack(amults)),
-           "const": {k: _pad_n(np.asarray([c[k] for c in consts],
-                                          np.float32))
-                     for k in consts[0]},
-           "combo_w": combo_w,
-           "dt_s": np.float32(dt_s)}
-    ix = {"lvl_row": _pad_n(np.stack(lvl_row)),
-          "seg_of": _pad_n(np.stack(seg_of)),
-          "steady_of": _pad_n(np.asarray(steady_of, np.int32)),
-          "ambient": _pad_n(np.stack(ambs)),
-          "active": _pad_n(np.stack(acts)),
-          "valid": _pad_n(np.stack(vals)),
-          "charge": _pad_n(np.stack(chgs)),
-          "charge_p": _pad_n(np.stack(chgs_p))}
+        combo_w = np.zeros(n_b, np.float32)
+        combo_w[:n_real] = 1.0
+        dyn = {"groups": tuple(grp_dyn),
+               "rates": np.asarray(rr["tok_per_cap"], np.float32),
+               "gate": np.float32(n_users),
+               "act_mult": _pad_n(np.stack(amults)),
+               "const": {k: _pad_n(np.asarray([c[k] for c in consts],
+                                              np.float32))
+                         for k in consts[0]},
+               "combo_w": combo_w,
+               "dt_s": np.float32(dt_s)}
+        ix = {"lvl_row": _pad_n(np.stack(lvl_row)),
+              "seg_of": _pad_n(np.stack(seg_of)),
+              "steady_of": _pad_n(np.asarray(steady_of, np.int32)),
+              "ambient": _pad_n(np.stack(ambs)),
+              "active": _pad_n(np.stack(acts)),
+              "valid": _pad_n(np.stack(vals)),
+              "charge": _pad_n(np.stack(chgs)),
+              "charge_p": _pad_n(np.stack(chgs_p))}
 
-    plats = tuple(plat for plat, _ in groups)
-    sig = ("fused", plats, tuple(theta_keys), tuple(row_counts),
-           n_b, T, L, len(rr["tok_per_cap"]))
-    asm = _Assembly(combos, skipped, dyn, ix, plats, sig, key, n_real,
-                    float(n_users), float(dt_s))
-    _ASSEMBLIES[key] = asm
-    while len(_ASSEMBLIES) > _ASSEMBLIES_MAX:
-        del _ASSEMBLIES[next(iter(_ASSEMBLIES))]
-        ASSEMBLY_STATS["evictions"] += 1
+        plats = tuple(plat for plat, _ in groups)
+        sig = ("fused", plats, tuple(theta_keys), tuple(row_counts),
+               n_b, T, L, len(rr["tok_per_cap"]))
+        asm = _Assembly(combos, skipped, dyn, ix, plats, sig, key, n_real,
+                        float(n_users), float(dt_s))
+        _ASSEMBLIES[key] = asm
+        while len(_ASSEMBLIES) > _ASSEMBLIES_MAX:
+            del _ASSEMBLIES[next(iter(_ASSEMBLIES))]
+            ASSEMBLY_STATS["evictions"] += 1
     return asm
 
 
@@ -1808,31 +1825,37 @@ def day_grid_batch(queries, backend: str = "xla", **shared) -> list:
     k = len(asms)
     k_b = bucket_size(k)
     stacked = asms + [asms[0]] * (k_b - k)
-    dyn_k = jax.tree_util.tree_map(
-        lambda *xs: jnp.asarray(np.stack(xs)),
-        *[a.dyn for a in stacked])
-    ix_k = jax.tree_util.tree_map(
-        lambda *xs: jnp.asarray(np.stack(xs)),
-        *[a.ix for a in stacked])
+    with phases.phase("daysim.push", items=k):
+        dyn_k = jax.tree_util.tree_map(
+            lambda *xs: jnp.asarray(np.stack(xs)),
+            *[a.dyn for a in stacked])
+        ix_k = jax.tree_util.tree_map(
+            lambda *xs: jnp.asarray(np.stack(xs)),
+            *[a.ix for a in stacked])
     fn = _cached_executable(
         ("batch", k_b) + sig0 + (backend,),
         lambda: _jit_pipeline(_build_fused_batch(asms[0].plats,
                                                  backend)))
-    out = dict(fn(dyn_k, ix_k))
-    jax.block_until_ready(out["shutdown"])
+    with phases.phase("daysim.dispatch", items=k):
+        out = dict(fn(dyn_k, ix_k))
+    with phases.phase("daysim.wait", items=k):
+        jax.block_until_ready(out["shutdown"])
+    with phases.phase("daysim.fetch", items=k):
+        fetched = [_host_summary({kk: v[i] for kk, v in out.items()},
+                                 asm.n_real)
+                   for i, asm in enumerate(asms)]
     reports = []
-    for i, asm in enumerate(asms):
-        summ = {kk: v[i] for kk, v in out.items()}
-        front, steady, host = _host_summary(summ, asm.n_real)
-        rep = DayReport(
-            combos=[cb.label() for cb in asm.combos],
-            steady_mw=steady, n_users=asm.n_users, dt_s=asm.dt_s,
-            skipped=asm.skipped,
-            battery_fade=np.asarray([cb.battery.fade
-                                     for cb in asm.combos]),
-            **host)
-        rep.front_mask = front
-        reports.append(rep)
+    with phases.phase("daysim.report", items=k):
+        for asm, (front, steady, host) in zip(asms, fetched):
+            rep = DayReport(
+                combos=[cb.label() for cb in asm.combos],
+                steady_mw=steady, n_users=asm.n_users, dt_s=asm.dt_s,
+                skipped=asm.skipped,
+                battery_fade=np.asarray([cb.battery.fade
+                                         for cb in asm.combos]),
+                **host)
+            rep.front_mask = front
+            reports.append(rep)
     return reports
 
 
@@ -1868,17 +1891,22 @@ def day_grid(platforms=DEFAULT_PLATFORMS, designs=DEFAULT_DESIGNS,
                                dt_s, n_users, standby_mw, battery,
                                thermal, theta, results_dir, shutdown_c,
                                backend)
-        dyn = jax.tree_util.tree_map(jnp.asarray, pipe.dyn)
-        summ = dict(pipe.fn(dyn, pipe.ix))
-        jax.block_until_ready(summ["shutdown"])
-        front, steady, host = _host_summary(summ, pipe.n_real)
-        rep = DayReport(
-            combos=[cb.label() for cb in pipe.combos],
-            steady_mw=steady, n_users=n_users, dt_s=dt_s,
-            skipped=pipe.skipped,
-            battery_fade=np.asarray([cb.battery.fade
-                                     for cb in pipe.combos]),
-            **host)
+        with phases.phase("daysim.push", items=1):
+            dyn = jax.tree_util.tree_map(jnp.asarray, pipe.dyn)
+        with phases.phase("daysim.dispatch", items=1):
+            summ = dict(pipe.fn(dyn, pipe.ix))
+        with phases.phase("daysim.wait", items=1):
+            jax.block_until_ready(summ["shutdown"])
+        with phases.phase("daysim.fetch", items=1):
+            front, steady, host = _host_summary(summ, pipe.n_real)
+        with phases.phase("daysim.report", items=1):
+            rep = DayReport(
+                combos=[cb.label() for cb in pipe.combos],
+                steady_mw=steady, n_users=n_users, dt_s=dt_s,
+                skipped=pipe.skipped,
+                battery_fade=np.asarray([cb.battery.fade
+                                         for cb in pipe.combos]),
+                **host)
         if with_front:
             rep.front_mask = front
         return rep

@@ -22,6 +22,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..core.phases import phase
 from ..nn import core
 
 
@@ -171,11 +172,12 @@ def measured_flops() -> dict[str, float]:
         c = jax.jit(lambda *a: fn(key, *a)).lower(*args).compile()
         return float(c.cost_analysis()["flops"])
 
-    return {
-        "hand_tracker": flops(hand_tracker, (1, 2, 128, 128, 1)),
-        "eye_tracker": flops(eye_tracker, (1, 2, 96, 96, 1)),
-        "vio_imu": flops(vio_imu_net, (1, 200, 6)),
-        "vio_frontend": flops(vio_frontend, (1, 240, 320, 1)),
-        "vad": flops(vad, (1, 100, 40)),
-        "asr_1s": flops(asr_conformer, (1, 100, 80)),
-    }
+    with phase("nets.measured_flops"):
+        return {
+            "hand_tracker": flops(hand_tracker, (1, 2, 128, 128, 1)),
+            "eye_tracker": flops(eye_tracker, (1, 2, 96, 96, 1)),
+            "vio_imu": flops(vio_imu_net, (1, 200, 6)),
+            "vio_frontend": flops(vio_frontend, (1, 240, 320, 1)),
+            "vad": flops(vad, (1, 100, 40)),
+            "asr_1s": flops(asr_conformer, (1, 100, 80)),
+        }

@@ -43,22 +43,35 @@ tests/test_twin_serving.py.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from dataclasses import dataclass, field
 
 from .. import compat
 from ..core import daysim, dse
+from ..core.phases import phase
 from .engine import drain_microbatched
 
 
 @dataclass
 class WhatIf:
-    """One queued what-if: override kwargs in, report + latency out."""
+    """One queued what-if: override kwargs in, report + latency out.
+
+    `submitted_s` and `finished_s` are `time.perf_counter()` readings at
+    `submit()` and at the end of the micro-batch that answered it; `ms`
+    is this item's own latency between the two."""
     qid: int
     overrides: dict
     report: object = None
-    ms: float = 0.0
+    submitted_s: float = 0.0
+    finished_s: float = 0.0
+
+    @property
+    def ms(self) -> float:
+        if not self.finished_s:
+            return 0.0
+        return (self.finished_s - self.submitted_s) * 1e3
 
 
 @dataclass
@@ -110,6 +123,7 @@ class DesignTwin:
         self.queue: list[WhatIf] = []
         self.stats = TwinStats()
         self._qid = 0
+        self._batch_ids = itertools.count(1)
         self._lock = threading.Lock()
         if warm:
             self.query()
@@ -161,12 +175,13 @@ class DesignTwin:
             before = dict(daysim.EXEC_STATS)
             t0 = time.perf_counter()
             groups: dict = {}
-            for i, q in enumerate(queries):
-                kw = daysim._batch_defaults()
-                kw.update(args)
-                kw.update(q)
-                sig = daysim._assemble_query(**kw).sig
-                groups.setdefault(sig, []).append(i)
+            with phase("twin.group", items=len(queries)):
+                for i, q in enumerate(queries):
+                    kw = daysim._batch_defaults()
+                    kw.update(args)
+                    kw.update(q)
+                    sig = daysim._assemble_query(**kw).sig
+                    groups.setdefault(sig, []).append(i)
             for idx in groups.values():
                 reps = dse.day_pareto_batch(
                     [queries[i] for i in idx], backend=backend, **args)
@@ -201,22 +216,26 @@ class DesignTwin:
         """Enqueue a what-if; returns its query id."""
         with self._lock:
             self._qid += 1
-            self.queue.append(WhatIf(self._qid, overrides))
+            self.queue.append(WhatIf(self._qid, overrides,
+                                     submitted_s=time.perf_counter()))
             return self._qid
 
     def run(self, max_steps: int = 64) -> list[WhatIf]:
         """Drain the queue in micro-batches of up to `batch_window`
         concurrent submissions (at most `max_steps` queries total);
         each batch is evaluated through `what_if_many` — one compiled
-        program per shape-signature group — and every finished WhatIf
-        carries its report + its share of the batch latency."""
+        program per shape-signature group, under one ``repro.twin.batch``
+        phase whose id its nested phases carry — and every finished
+        WhatIf carries its report and its finish time."""
 
         def eval_batch(batch: list[WhatIf]) -> list[WhatIf]:
-            reps = self.what_if_many([wi.overrides for wi in batch])
-            per_ms = self.stats.last_ms / max(len(batch), 1)
+            with phase("twin.batch", batch=next(self._batch_ids),
+                       items=len(batch), queued=len(self.queue)):
+                reps = self.what_if_many([wi.overrides for wi in batch])
+            done = time.perf_counter()
             for wi, rep in zip(batch, reps):
                 wi.report = rep
-                wi.ms = per_ms
+                wi.finished_s = done
             return batch
 
         return drain_microbatched(self.queue, self.batch_window,

@@ -237,16 +237,20 @@ def test_concurrent_submit_run_mixed_shapes(twin):
 
 def test_cache_stats_accessor(twin):
     stats = daysim.cache_stats()
-    assert set(stats) == {"rows", "assemblies", "pipelines", "exec"}
-    for tier in stats.values():
-        assert {"hits", "misses", "size"} <= set(tier)
+    assert set(stats) == {"rows", "assemblies", "pipelines", "exec",
+                          "phases"}
+    for name, tier in stats.items():
+        if name != "phases":
+            assert {"hits", "misses", "size"} <= set(tier)
     a0 = stats["assemblies"]["hits"]
     p0 = stats["pipelines"]["hits"]
+    d0 = stats["phases"].get("repro.daysim.dispatch", {"calls": 0})["calls"]
     twin.query()
     twin.query()                        # identical: every tier hits
     stats = daysim.cache_stats()
     assert stats["assemblies"]["hits"] >= a0 + 2
     assert stats["pipelines"]["hits"] >= p0 + 2
+    assert stats["phases"]["repro.daysim.dispatch"]["calls"] >= d0 + 2
     assert stats["exec"]["size"] >= 1
     assert stats["rows"]["evictions"] >= 0
 
