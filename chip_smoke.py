@@ -235,8 +235,6 @@ def phase_twin_xla(grid: dict, dt_s: float):
 
 
 def phase_twin_pallas(grid: dict, dt_s: float, base, base_ref) -> None:
-    import jax
-    import jax.numpy as jnp
     import numpy as np
     from repro.core import daysim
     from repro.serving.twin import DesignTwin
@@ -248,8 +246,7 @@ def phase_twin_pallas(grid: dict, dt_s: float, base, base_ref) -> None:
     # executable that just ran
     kw = {**daysim._batch_defaults(), **grid, "dt_s": dt_s}
     pipe = daysim._fused_pipeline(**kw, backend="pallas")
-    text = pipe.fn.lower(jax.tree_util.tree_map(jnp.asarray, pipe.dyn),
-                         pipe.ix).as_text()
+    text = pipe.fn.lower(pipe.dyn, pipe.ix).as_text()
     check("pallas_kernel_compiled", "tpu_custom_call" in text)
     say(f"  vs xla: {_differences(rep, base)}")
     _check_against_oracle("pallas", rep, base_ref)

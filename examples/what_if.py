@@ -59,9 +59,10 @@ print(f"\n{st.queries} queries in {st.batches} batched executions: "
 
 # every daysim cache tier in one snapshot: scenario-row tables, host
 # assemblies, value-keyed pipelines, compiled executables; then the host
-# phases' counters
+# phases' counters and the host<->device transfers
 stats = daysim.cache_stats()
 host_phases = stats.pop("phases")
+transfers = stats.pop("transfers")
 for tier, s in stats.items():
     extras = "".join(f", {k}={s[k]}" for k in ("evictions", "traces")
                      if k in s)
@@ -70,3 +71,7 @@ for tier, s in stats.items():
 for name, s in sorted(host_phases.items()):
     print(f"phase[{name}]: {s['calls']} calls, "
           f"{s['total_ns'] / 1e6:.1f} ms")
+print(f"transfers: {transfers['h2d_calls']} pushes "
+      f"({transfers['h2d_bytes'] / 1e6:.1f} MB), "
+      f"{transfers['d2h_calls']} fetches "
+      f"({transfers['d2h_bytes'] / 1e3:.1f} kB)")

@@ -60,6 +60,8 @@ and survival flags are bit-identical across engines; see
 from __future__ import annotations
 
 import functools
+import math
+import threading
 from dataclasses import dataclass, field
 
 import jax
@@ -910,14 +912,17 @@ def cache_stats() -> dict:
     pin).  The FIFO tiers evict silently during queries; this accessor
     is how benchmarks and `examples/what_if.py` make that visible.
     ``phases`` is a copy of `phases.PHASE_STATS`: the host phases' call
-    counts, times and duration histograms (`clear_exec_cache` leaves
-    them as they are)."""
+    counts, times and duration histograms; ``transfers`` counts the
+    fused programs' host->device pushes and device->host fetches
+    (`h2d_calls`, `h2d_bytes`, `d2h_calls`, `d2h_bytes`).
+    `clear_exec_cache` leaves those two as they are."""
     return {
         "rows": {**CACHE_STATS, "size": len(_ROW_CACHE)},
         "assemblies": {**ASSEMBLY_STATS, "size": len(_ASSEMBLIES)},
         "pipelines": {**PIPELINE_STATS, "size": len(_PIPELINES)},
         "exec": {**EXEC_STATS, "size": len(_EXEC_CACHE)},
         "phases": phases.snapshot(),
+        "transfers": _transfer_snapshot(),
     }
 
 
@@ -941,11 +946,10 @@ def bucket_size(n: int) -> int:
 def _jit_pipeline(fn):
     """Jit wrapper for the fused day program.
 
-    The per-query `dyn` pytree (arg 0) is donated on accelerator
-    backends: it is re-pushed from host masters on every query, so its
-    device buffers are dead after the call and XLA may reuse them for
-    the (N, T, L) gathered tables.  CPU runs (tests/CI) do not support
-    buffer donation — jit plain there to avoid the warning."""
+    The per-query packed `dyn` buffers (arg 0) are donated on
+    accelerator backends: they are re-pushed from host masters on every
+    query, so they are dead after the call.  CPU runs (tests/CI) do not
+    support buffer donation — jit plain there to avoid the warning."""
     if jax.default_backend() == "cpu":
         return jax.jit(fn)
     return jax.jit(fn, donate_argnums=(0,))
@@ -1462,9 +1466,10 @@ class _Assembly:
     """Host half of one fully-valued fused query, padded to canonical
     bucket shapes: numpy masters for the value-level inputs (`dyn`),
     numpy gather indices / step data (`ix`), and the static signature
-    the compiled executable is keyed by.  Backend-independent — the
-    single-query path pushes `ix` to the device once (`_Pipeline`),
-    the batch path stacks K assemblies along a leading query axis."""
+    the compiled executable is keyed by, with both trees packed into
+    one buffer per dtype.  Backend-independent — the single-query path
+    pushes the packed `ix` to the device once (`_Pipeline`), the batch
+    path stacks K assemblies' buffers along a leading query axis."""
     combos: list
     skipped: list
     dyn: dict               # numpy masters (incl. combo_w), bucketed
@@ -1475,18 +1480,21 @@ class _Assembly:
     n_real: int             # combos before bucket padding
     n_users: float
     dt_s: float
+    layouts: tuple          # (`dyn`, `ix`) `_Layout`s, fixed by `sig`
+    packed: tuple           # (`dyn`, `ix`) packed by `layouts`
 
 
 @dataclass
 class _Pipeline:
-    """One assembled fused-day query: host masters + device indices +
-    the compiled program.  `dyn` is re-pushed from numpy every call
-    (donation-safe); `ix` stays resident on the device."""
+    """One assembled fused-day query: packed host masters + packed
+    device indices + the compiled program.  `dyn` is re-pushed from
+    numpy every call (donation-safe); `ix` stays resident on the
+    device."""
     combos: list
     skipped: list
-    dyn: dict               # numpy masters, pushed per query
-    ix: dict                # device-resident gather indices / step data
-    fn: object              # jitted fused(dyn, ix) -> summary dict
+    dyn: tuple              # packed numpy masters, pushed per query
+    ix: tuple               # packed gather indices / step data, on device
+    fn: object              # jitted packed(dyn, ix) -> packed summary
     n_real: int             # combos before bucket padding
 
 
@@ -1579,6 +1587,114 @@ def _build_fused_batch(plats: tuple, backend: str):
         return jax.vmap(fused)(dyn, ix)
 
     return fused_batch
+
+
+# ---------------------------------------------------------------------------
+# the host-device boundary: one transfer each way per call
+# ---------------------------------------------------------------------------
+
+# pushes and fetches of the fused programs, counted where they are made
+TRANSFER_STATS = {"h2d_calls": 0, "h2d_bytes": 0, "d2h_calls": 0,
+                  "d2h_bytes": 0}
+_TRANSFER_LOCK = threading.Lock()       # concurrent `run()`s count here
+_LAYOUTS: dict = {}
+# a fused program's summary fields in packed order; the two flags cross
+# the boundary as float32 0/1
+_SUMMARY_KEYS = ("day_hours", "time_to_empty_h", "end_soc", "end_soc_puck",
+                 "peak_skin_c", "peak_skin_puck_c", "pod_hours",
+                 "throttled_h", "energy_mwh", "steady_mw", "shutdown",
+                 "front_mask")
+_SUMMARY_FLAGS = ("shutdown", "front_mask")
+
+
+@dataclass(frozen=True)
+class _Layout:
+    """Where each leaf of a pytree of arrays lies once packed: one flat
+    buffer per dtype (`dtypes`, `sizes` elements each), every leaf a
+    contiguous run of one buffer."""
+    treedef: object
+    dtypes: tuple
+    sizes: tuple
+    leaves: tuple           # (buffer index, offset, shape) per leaf
+
+
+def _layout_of(tree) -> _Layout:
+    arrs = [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
+    dtypes = tuple(sorted({a.dtype for a in arrs}, key=str))
+    sizes, leaves = [0] * len(dtypes), []
+    for a in arrs:
+        b = dtypes.index(a.dtype)
+        leaves.append((b, sizes[b], a.shape))
+        sizes[b] += a.size
+    return _Layout(jax.tree_util.tree_structure(tree), dtypes,
+                   tuple(sizes), tuple(leaves))
+
+
+def _pack(layout: _Layout, tree) -> tuple:
+    """Host pytree -> one contiguous numpy buffer per layout dtype."""
+    parts = [[] for _ in layout.dtypes]
+    for (b, _, _), x in zip(layout.leaves, jax.tree_util.tree_leaves(tree)):
+        parts[b].append(np.ravel(x))
+    return tuple(np.concatenate(p) for p in parts)
+
+
+def _unpack(layout: _Layout, bufs):
+    """Packed buffers -> the pytree, in numpy or inside a traced program
+    (static slices and reshapes); axes in front of the packed one (a
+    batch) lead every leaf."""
+    lead = bufs[0].shape[:-1]
+    return layout.treedef.unflatten(
+        [bufs[b][..., o:o + math.prod(shape)].reshape(lead + shape)
+         for b, o, shape in layout.leaves])
+
+
+def _build_packed(body, layouts: tuple):
+    """Wrap a fused day program (`_build_fused` or `_build_fused_batch`)
+    so it crosses the host-device boundary once each way: it takes
+    `dyn` and `ix` packed by `layouts` and returns its summary fields
+    stacked in `_SUMMARY_KEYS` order, one float32 (..., 12, N) array.
+    The wrapper keeps the body's name, so the compiled module is still
+    `jit_fused` / `jit_fused_batch` in a device trace."""
+    dyn_layout, ix_layout = layouts
+
+    @functools.wraps(body)
+    def packed(dyn, ix):
+        summ = body(_unpack(dyn_layout, dyn), _unpack(ix_layout, ix))
+        assert set(summ) == set(_SUMMARY_KEYS), sorted(summ)
+        return jnp.stack([summ[k].astype(jnp.float32)
+                          for k in _SUMMARY_KEYS], axis=-2)
+
+    return packed
+
+
+def _transfer_snapshot() -> dict:
+    with _TRANSFER_LOCK:
+        return dict(TRANSFER_STATS)
+
+
+def _push(bufs):
+    """Host buffers -> device in one `jax.device_put` call."""
+    nbytes = sum(b.nbytes for b in jax.tree_util.tree_leaves(bufs))
+    with _TRANSFER_LOCK:
+        TRANSFER_STATS["h2d_calls"] += 1
+        TRANSFER_STATS["h2d_bytes"] += nbytes
+    return jax.device_put(bufs)
+
+
+def _fetch(out) -> np.ndarray:
+    """A packed summary -> host in one copy."""
+    host = np.asarray(out)
+    with _TRANSFER_LOCK:
+        TRANSFER_STATS["d2h_calls"] += 1
+        TRANSFER_STATS["d2h_bytes"] += host.nbytes
+    return host
+
+
+def _split_summary(out: np.ndarray) -> dict:
+    """One query's packed (12, N) summary -> the program's summary dict
+    (flags back to bool)."""
+    return {k: out[j] != 0 if k in _SUMMARY_FLAGS else out[j]
+            for j, k in enumerate(_SUMMARY_KEYS)}
 
 
 def _assemble_query(platforms, designs, schedules, policies, dt_s,
@@ -1717,8 +1833,13 @@ def _assemble_query(platforms, designs, schedules, policies, dt_s,
         plats = tuple(plat for plat, _ in groups)
         sig = ("fused", plats, tuple(theta_keys), tuple(row_counts),
                n_b, T, L, len(rr["tok_per_cap"]))
+        # the signature fixes every leaf's shape, so the layout too
+        layouts = _LAYOUTS.get(sig)
+        if layouts is None:
+            layouts = _LAYOUTS[sig] = (_layout_of(dyn), _layout_of(ix))
         asm = _Assembly(combos, skipped, dyn, ix, plats, sig, key, n_real,
-                        float(n_users), float(dt_s))
+                        float(n_users), float(dt_s), layouts,
+                        (_pack(layouts[0], dyn), _pack(layouts[1], ix)))
         _ASSEMBLIES[key] = asm
         while len(_ASSEMBLIES) > _ASSEMBLIES_MAX:
             del _ASSEMBLIES[next(iter(_ASSEMBLIES))]
@@ -1751,10 +1872,10 @@ def _fused_pipeline(platforms, designs, schedules, policies, dt_s,
     PIPELINE_STATS["misses"] += 1
     fn = _cached_executable(
         asm.sig + (backend,),
-        lambda: _jit_pipeline(_build_fused(asm.plats, backend)))
-    pipe = _Pipeline(asm.combos, asm.skipped, asm.dyn,
-                     jax.tree_util.tree_map(jnp.asarray, asm.ix), fn,
-                     asm.n_real)
+        lambda: _jit_pipeline(_build_packed(_build_fused(asm.plats, backend),
+                                            asm.layouts)))
+    pipe = _Pipeline(asm.combos, asm.skipped, asm.packed[0],
+                     _push(asm.packed[1]), fn, asm.n_real)
     _PIPELINES[key] = pipe
     while len(_PIPELINES) > _PIPELINES_MAX:
         del _PIPELINES[next(iter(_PIPELINES))]
@@ -1793,11 +1914,12 @@ def day_grid_batch(queries, backend: str = "xla", **shared) -> list:
     buckets) — value-level differences (designs, thresholds,
     batteries, n_users, ambients) are exactly what the leading axis
     carries.  Queries are assembled on the host (value-cached), padded
-    to a `bucket_size(K)` batch with clones of query 0, stacked leaf
-    by leaf and pushed once; the batch executable is `jax.vmap` over
-    the single-query fused body, so every lane's front mask and
-    survival flags are bit-identical to the serial query's.  Returns
-    one `DayReport` per query (front attached), pad lanes discarded.
+    to a `bucket_size(K)` batch with clones of query 0, their packed
+    buffers stacked and pushed in one call; the batch executable is
+    `jax.vmap` over the single-query fused body, so every lane's front
+    mask and survival flags are bit-identical to the serial query's,
+    and its packed summaries come back in one copy.  Returns one
+    `DayReport` per query (front attached), pad lanes discarded.
 
     Only the "xla" backend batches (the pallas day kernel has no batch
     grid); serial `day_grid(backend="pallas")` remains available."""
@@ -1826,23 +1948,19 @@ def day_grid_batch(queries, backend: str = "xla", **shared) -> list:
     k_b = bucket_size(k)
     stacked = asms + [asms[0]] * (k_b - k)
     with phases.phase("daysim.push", items=k):
-        dyn_k = jax.tree_util.tree_map(
-            lambda *xs: jnp.asarray(np.stack(xs)),
-            *[a.dyn for a in stacked])
-        ix_k = jax.tree_util.tree_map(
-            lambda *xs: jnp.asarray(np.stack(xs)),
-            *[a.ix for a in stacked])
+        dyn_k, ix_k = _push(jax.tree_util.tree_map(
+            lambda *xs: np.stack(xs), *[a.packed for a in stacked]))
     fn = _cached_executable(
         ("batch", k_b) + sig0 + (backend,),
-        lambda: _jit_pipeline(_build_fused_batch(asms[0].plats,
-                                                 backend)))
+        lambda: _jit_pipeline(_build_packed(
+            _build_fused_batch(asms[0].plats, backend), asms[0].layouts)))
     with phases.phase("daysim.dispatch", items=k):
-        out = dict(fn(dyn_k, ix_k))
+        out = fn(dyn_k, ix_k)
     with phases.phase("daysim.wait", items=k):
-        jax.block_until_ready(out["shutdown"])
+        jax.block_until_ready(out)
     with phases.phase("daysim.fetch", items=k):
-        fetched = [_host_summary({kk: v[i] for kk, v in out.items()},
-                                 asm.n_real)
+        out = _fetch(out)
+        fetched = [_host_summary(_split_summary(out[i]), asm.n_real)
                    for i, asm in enumerate(asms)]
     reports = []
     with phases.phase("daysim.report", items=k):
@@ -1892,13 +2010,14 @@ def day_grid(platforms=DEFAULT_PLATFORMS, designs=DEFAULT_DESIGNS,
                                thermal, theta, results_dir, shutdown_c,
                                backend)
         with phases.phase("daysim.push", items=1):
-            dyn = jax.tree_util.tree_map(jnp.asarray, pipe.dyn)
+            dyn = _push(pipe.dyn)
         with phases.phase("daysim.dispatch", items=1):
-            summ = dict(pipe.fn(dyn, pipe.ix))
+            out = pipe.fn(dyn, pipe.ix)
         with phases.phase("daysim.wait", items=1):
-            jax.block_until_ready(summ["shutdown"])
+            jax.block_until_ready(out)
         with phases.phase("daysim.fetch", items=1):
-            front, steady, host = _host_summary(summ, pipe.n_real)
+            front, steady, host = _host_summary(
+                _split_summary(_fetch(out)), pipe.n_real)
         with phases.phase("daysim.report", items=1):
             rep = DayReport(
                 combos=[cb.label() for cb in pipe.combos],

@@ -238,10 +238,12 @@ def test_concurrent_submit_run_mixed_shapes(twin):
 def test_cache_stats_accessor(twin):
     stats = daysim.cache_stats()
     assert set(stats) == {"rows", "assemblies", "pipelines", "exec",
-                          "phases"}
+                          "phases", "transfers"}
     for name, tier in stats.items():
-        if name != "phases":
+        if name not in ("phases", "transfers"):
             assert {"hits", "misses", "size"} <= set(tier)
+    assert set(stats["transfers"]) == {"h2d_calls", "h2d_bytes",
+                                       "d2h_calls", "d2h_bytes"}
     a0 = stats["assemblies"]["hits"]
     p0 = stats["pipelines"]["hits"]
     d0 = stats["phases"].get("repro.daysim.dispatch", {"calls": 0})["calls"]
