@@ -106,6 +106,66 @@ class ModelConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentMoEConfig(ModelConfig):
+    """The DeepSeek-V3 block (Moonlight): multi-head latent attention over a
+    cached latent, then `first_k_dense` SwiGLU layers of width `d_ff` and
+    routed-expert layers after them.
+
+    Attention: q = h Wq (direct, no query latent), per head `qk_nope_head_dim`
+    + `qk_rope_head_dim`; [c, k_pe] = h Wkva, c = RMSNorm(c) is the cached
+    latent (`kv_lora_rank`) and k_pe the one rope key all heads share; per
+    head [k_nope, v] = c Wkvb.  RoPE touches the rope parts only; the scale
+    is 1/sqrt(nope + rope).
+
+    Routed layers: sigmoid scores of a float32 router; the top `top_k` of
+    score + a per-expert correction bias are selected; their gate weights
+    are the scores without the bias, normalised when `norm_topk_prob`, times
+    `routed_scaling`.  `n_experts` experts of width `moe_d_ff`, dropless,
+    plus `n_shared_experts` shared experts (one SwiGLU of their summed
+    width) added unweighted.  The output head is untied.  RMSNorm eps is
+    `norm_eps` everywhere.
+
+    The fields live on this subclass so that `ModelConfig`'s own fields,
+    which other configurations are compared against field by field, do not
+    change."""
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    first_k_dense: int = 0
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    norm_eps: float = 1e-6
+
+    def __post_init__(self):
+        if self.head_dim == 0:
+            object.__setattr__(self, "head_dim",
+                               self.qk_nope_head_dim + self.qk_rope_head_dim)
+
+    @property
+    def n_params(self) -> int:
+        D, H, R, V = self.d_model, self.n_heads, self.kv_lora_rank, self.vocab
+        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        attn = (D * H * (nope + rope) + D * (R + rope) + R
+                + R * H * (nope + vd) + H * vd * D + 2 * D)
+        dense = attn + 3 * D * self.d_ff
+        moe = (attn + D * self.n_experts + self.n_experts
+               + 3 * D * self.moe_d_ff * (self.n_experts
+                                          + self.n_shared_experts))
+        n_moe = self.n_layers - self.first_k_dense
+        return 2 * V * D + D + self.first_k_dense * dense + n_moe * moe
+
+    @property
+    def n_active_params(self) -> int:
+        n_moe = self.n_layers - self.first_k_dense
+        idle = 3 * self.d_model * self.moe_d_ff * (self.n_experts - self.top_k)
+        return self.n_params - n_moe * idle
+
+
+@dataclasses.dataclass(frozen=True)
 class ShapeConfig:
     name: str
     seq_len: int

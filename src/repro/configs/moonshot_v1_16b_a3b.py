@@ -1,5 +1,10 @@
-"""moonshot-v1-16b-a3b [hf:moonshotai/Moonlight-16B-A3B; hf] — fine-grained
-MoE, 64 experts top-6, d_ff=1408 per expert."""
+"""moonshot-v1-16b-a3b — the assignment sheet's shape under Moonlight's name:
+fine-grained MoE, 48 plain 16/16 MHA layers of 128, 64 experts of
+d_ff=1408, softmax top-6, no shared experts and no leading dense layer
+(27.7 B parameters).  It is not Moonlight's published block
+(hf:moonshotai/Moonlight-16B-A3B, `model_type: deepseek_v3`): that block,
+with latent attention, sigmoid routing, shared experts and a dense first
+layer, is `moonlight-16b-a3b` (configs/moonlight_16b_a3b.py)."""
 from .base import ModelConfig
 
 

@@ -203,7 +203,7 @@ def main():
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)
 
-    archs = registry.arch_names() if args.arch == "all" else [args.arch]
+    archs = list(registry.SHEET) if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
 

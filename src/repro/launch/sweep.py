@@ -78,7 +78,7 @@ def all_cells(archs=None, shapes=None, meshes=MESHES) -> list[tuple]:
     if archs is None or shapes is None:
         from ..configs.base import SHAPES
         from ..models import registry
-        archs = registry.arch_names() if archs is None else archs
+        archs = list(registry.SHEET) if archs is None else archs
         shapes = list(SHAPES) if shapes is None else shapes
     return [(a, s, m) for a in archs for s in shapes for m in meshes]
 
@@ -197,7 +197,7 @@ class CellTable:
     def build(cls, archs=None, shapes=None, meshes=MESHES) -> "CellTable":
         from ..configs.base import SHAPES, shape_applicable
         from ..models import registry
-        archs = registry.arch_names() if archs is None else list(archs)
+        archs = list(registry.SHEET) if archs is None else list(archs)
         shape_names = list(SHAPES) if shapes is None else list(shapes)
 
         # one pass over archs (10), columns assembled per cell below

@@ -23,6 +23,15 @@ ARCHS = {
     "dbrx-132b":           ("repro.configs.dbrx_132b", transformer),
 }
 
+# The assignment sheet's ten: the dry-run grid (`launch/dryrun.py`,
+# `launch/sweep.py`, `results/dryrun/`) covers these and no others.
+SHEET = tuple(ARCHS)
+
+# Served at its published widths through `serving/engine.py`, and outside
+# the dry-run grid (its latent attention and routed experts have no
+# sharded path there).
+ARCHS["moonlight-16b-a3b"] = ("repro.configs.moonlight_16b_a3b", transformer)
+
 
 def get(arch: str, smoke: bool = False):
     """Returns (ModelConfig, model module)."""

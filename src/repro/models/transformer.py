@@ -7,7 +7,14 @@ bases) is handled with *traced* per-layer flags inside the scan body, not
 python branching, so a single body serves every layer.
 
 Covers: olmo-1b, gemma3-4b, granite-3-2b, yi-34b, phi-3-vision-4.2b (vision
-stub), moonshot-v1-16b-a3b (MoE), dbrx-132b (MoE).
+stub), moonshot-v1-16b-a3b (MoE), dbrx-132b (MoE), and moonlight-16b-a3b.
+
+moonshot-v1-16b-a3b is the assignment sheet's shape under Moonlight's name
+(48 plain-MHA layers, softmax top-6 routing, no shared experts and no
+leading dense layer), not Moonlight's published block.  That block is
+moonlight-16b-a3b (`configs.base.LatentMoEConfig`): latent attention over a
+latent cache, a leading dense stack and a routed stack, each scanned with
+its own body, and an untied output head (the `_latent_*` functions below).
 """
 from __future__ import annotations
 
@@ -19,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..configs.base import LatentMoEConfig
 from ..nn import attention as attn_lib
 from ..nn import core, moe as moe_lib
 from ..nn.sharding import AxisEnv, constrain
@@ -59,6 +67,8 @@ def _layer_init(key, cfg, dtype) -> core.Params:
 
 
 def init(key, cfg) -> core.Params:
+    if isinstance(cfg, LatentMoEConfig):
+        return _latent_init(key, cfg)
     dtype = cfg.param_dtype
     ke, kl, kh, kv = jax.random.split(key, 4)
     layer_keys = jax.random.split(kl, cfg.n_layers)
@@ -183,6 +193,8 @@ def embed_tokens(params, cfg, tokens, vision_embeds=None):
 def forward(params, cfg, tokens, *, env: Optional[AxisEnv] = None,
             vision_embeds=None, remat: bool = True):
     """tokens: (B,S) -> hidden (B,S,D), moe aux loss (scalar)."""
+    if isinstance(cfg, LatentMoEConfig):
+        return _latent_forward(params, cfg, tokens, remat)
     h = embed_tokens(params, cfg, tokens, vision_embeds)
     h = constrain(h, env, _res_axes(cfg))
     flags = layer_flags(cfg)
@@ -248,7 +260,9 @@ def loss_fn(params, cfg, batch, *, env=None, remat=True):
     h, aux = forward(params, cfg, batch["tokens"], env=env,
                      vision_embeds=batch.get("vision_embeds"), remat=remat)
     mask = batch.get("mask")
-    ce = core.chunked_softmax_xent(params["embed"]["table"], h,
+    table = params["lm_head"].T if "lm_head" in params \
+        else params["embed"]["table"]
+    ce = core.chunked_softmax_xent(table, h,
                                    batch["labels"], mask,
                                    chunk=min(cfg.ce_chunk, h.shape[1]))
     return ce + cfg.moe_aux_weight * aux
@@ -259,6 +273,13 @@ def loss_fn(params, cfg, batch, *, env=None, remat=True):
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg, batch: int, max_len: int, dtype):
+    if isinstance(cfg, LatentMoEConfig):
+        L = cfg.n_layers
+        return {"ckv": jnp.zeros((L, batch, max_len, cfg.kv_lora_rank),
+                                 dtype),
+                "kpe": jnp.zeros((L, batch, max_len, cfg.qk_rope_head_dim),
+                                 dtype),
+                "experts_routed": jnp.zeros((), jnp.int32)}
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -268,6 +289,8 @@ def prefill(params, cfg, tokens, *, env=None, vision_embeds=None,
     """Run the full prompt; returns (last hidden (B,D), cache)."""
     B, S = tokens.shape
     max_len = max_len or S
+    if isinstance(cfg, LatentMoEConfig):
+        return _latent_prefill(params, cfg, tokens, max_len)
     h = embed_tokens(params, cfg, tokens, vision_embeds)
     h = constrain(h, env, ("batch", None, None))
     flags = layer_flags(cfg)
@@ -325,6 +348,8 @@ def decode_step(params, cfg, token, cache, cur_len, *, env=None,
                 serve_shard=None):
     """One decode step.  token: (B,) int32; cur_len: scalar count of valid
     positions.  Returns (logits (B,V), new cache)."""
+    if isinstance(cfg, LatentMoEConfig):
+        return _latent_decode_step(params, cfg, token, cache, cur_len)
     B = token.shape[0]
     h = core.embed_apply(params["embed"], token[:, None], cfg.compute_dtype)
     if cfg.embed_scale:
@@ -378,3 +403,169 @@ def decode_step(params, cfg, token, cache, cur_len, *, env=None,
     h = core.norm_apply(cfg.norm, params["final_norm"], h[:, None, :])[:, 0]
     logits = core.unembed_logits(params["embed"]["table"], h)
     return logits, {"k": ks, "v": vs}
+
+
+# ---------------------------------------------------------------------------
+# latent attention + routed experts (LatentMoEConfig, one device)
+# ---------------------------------------------------------------------------
+
+def _latent_layer_init(key, cfg, dtype, routed: bool) -> core.Params:
+    ka, km = jax.random.split(key)
+    D = cfg.d_model
+    p = {
+        "norm1": core.rmsnorm_init(D, dtype),
+        "attn": attn_lib.mla_init(ka, D, cfg.n_heads, cfg.kv_lora_rank,
+                                  cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                                  cfg.v_head_dim, dtype),
+        "norm2": core.rmsnorm_init(D, dtype),
+    }
+    if routed:
+        p["moe"] = moe_lib.routed_init(km, D, cfg.moe_d_ff, cfg.n_experts,
+                                       cfg.n_shared_experts, dtype)
+    else:
+        p["mlp"] = core.mlp_init(km, D, cfg.d_ff, dtype, gated=True)
+    return p
+
+
+def _latent_init(key, cfg) -> core.Params:
+    """`dense_layers` (the first `first_k_dense`) and `layers` (routed),
+    each stacked over its layers; `lm_head` (D, V) apart from `embed`."""
+    dtype = cfg.param_dtype
+    ke, kd, kl, kh = jax.random.split(key, 4)
+
+    def stack(k, n, routed):
+        return jax.vmap(lambda k_: _latent_layer_init(k_, cfg, dtype,
+                                                      routed))(
+            jax.random.split(k, n))
+
+    nd = cfg.first_k_dense
+    return {
+        "embed": core.embed_init_params(ke, cfg.vocab, cfg.d_model, dtype),
+        "dense_layers": stack(kd, nd, False),
+        "layers": stack(kl, cfg.n_layers - nd, True),
+        "final_norm": core.rmsnorm_init(cfg.d_model, dtype),
+        "lm_head": core.dense_init(kh, (cfg.d_model, cfg.vocab), dtype),
+    }
+
+
+def unembed(params, h):
+    """Logits of hidden states: the untied head where there is one, else
+    the embedding table."""
+    if "lm_head" in params:
+        return h @ params["lm_head"].astype(h.dtype)
+    return core.unembed_logits(params["embed"]["table"], h)
+
+
+def _split_experts(layers):
+    """The routed stack without its experts' matrices, and those matrices
+    with the layer and expert axes merged, (layers * E, ...).  A scan's
+    per-layer slice of an operand of the grouped-matmul kernel is copied
+    out whole, all E experts, every step; handed whole, the kernel reads
+    in place only the experts the rows chose (`moe.routed_apply`'s
+    `first`)."""
+    moe = layers["moe"]
+    experts = {k: moe[k].reshape((-1,) + moe[k].shape[2:])
+               for k in ("wi", "wg", "wo")}
+    rest = {k: v for k, v in moe.items() if k not in experts}
+    return dict(layers, moe=rest), experts
+
+
+def _latent_mlp(p, cfg, h, experts, layer):
+    """(output, distinct experts selected) of a layer's MLP part: the
+    dense MLP where `experts` is None, else routed layer `layer` of the
+    stack whose experts `experts` holds."""
+    if experts is None:
+        return core.mlp_apply(p["mlp"], h), jnp.zeros((), jnp.int32)
+    return moe_lib.routed_apply(dict(p["moe"], **experts), h,
+                                top_k=cfg.top_k, scaling=cfg.routed_scaling,
+                                norm_topk=cfg.norm_topk_prob,
+                                first=layer * cfg.n_experts)
+
+
+def _latent_block(p, cfg, x, experts, layer):
+    """One layer over whole sequences -> (x, (latent, rope key, distinct
+    experts))."""
+    eps = cfg.norm_eps
+    h = core.rmsnorm_apply(p["norm1"], x, eps)
+    a, c, k_pe = attn_lib.mla_full(p["attn"], h, cfg.rope_theta, eps,
+                                   cfg.qk_nope_head_dim)
+    x = x + a
+    m, n = _latent_mlp(p, cfg, core.rmsnorm_apply(p["norm2"], x, eps),
+                       experts, layer)
+    return x + m, (c, k_pe, n)
+
+
+def _latent_stacks(params, cfg, h, body, dense_xs=(), routed_xs=()):
+    """The dense stack, then the routed stack, each scanned with its own
+    body `body(experts)` over ((layer params, layer index), *xs); returns
+    (h, dense outputs, routed outputs)."""
+    nd = cfg.first_k_dense
+    rest, experts = _split_experts(params["layers"])
+    h, out_d = jax.lax.scan(
+        body(None), h, ((params["dense_layers"], jnp.arange(nd)),) + dense_xs)
+    h, out_m = jax.lax.scan(
+        body(experts), h,
+        ((rest, jnp.arange(cfg.n_layers - nd)),) + routed_xs)
+    return h, out_d, out_m
+
+
+def _latent_forward(params, cfg, tokens, remat: bool):
+    h = embed_tokens(params, cfg, tokens)
+
+    def body(experts):
+        def f(x, xs):
+            (p, i), = xs
+            return _latent_block(p, cfg, x, experts, i)[0], None
+        return jax.checkpoint(f, policy=_remat_policy(cfg)) if remat else f
+
+    h, _, _ = _latent_stacks(params, cfg, h, body)
+    return (core.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps),
+            jnp.zeros((), jnp.float32))
+
+
+def _latent_prefill(params, cfg, tokens, max_len: int):
+    S = tokens.shape[1]
+    pad = [(0, 0), (0, max_len - S), (0, 0)]
+    h = embed_tokens(params, cfg, tokens)
+
+    def body(experts):
+        def f(x, xs):
+            (p, i), = xs
+            x, (c, k_pe, n) = _latent_block(p, cfg, x, experts, i)
+            return x, (jnp.pad(c, pad), jnp.pad(k_pe, pad), n)
+        return f
+
+    h, (cd, pd, _), (cm, pm, n) = _latent_stacks(params, cfg, h, body)
+    h = core.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
+    return h[:, -1, :], {"ckv": jnp.concatenate([cd, cm]),
+                         "kpe": jnp.concatenate([pd, pm]),
+                         "experts_routed": jnp.sum(n)}
+
+
+def _latent_decode_step(params, cfg, token, cache, cur_len):
+    """One token a row through both stacks and the latent cache; adds the
+    distinct experts each routed layer selected to the cache's
+    `experts_routed` counter."""
+    eps, nd = cfg.norm_eps, cfg.first_k_dense
+    h = core.embed_apply(params["embed"], token, cfg.compute_dtype)
+
+    def body(experts):
+        def f(x, xs):
+            (p, i), c, pe = xs
+            a, c, pe = attn_lib.mla_decode(
+                p["attn"], core.rmsnorm_apply(p["norm1"], x, eps), c, pe,
+                cur_len, cfg.rope_theta, eps, cfg.qk_nope_head_dim)
+            x = x + a
+            m, n = _latent_mlp(p, cfg,
+                               core.rmsnorm_apply(p["norm2"], x, eps)[:, None],
+                               experts, i)
+            return x + m[:, 0], (c, pe, n)
+        return f
+
+    ckv, kpe = cache["ckv"], cache["kpe"]
+    h, (cd, pd, _), (cm, pm, n) = _latent_stacks(
+        params, cfg, h, body, (ckv[:nd], kpe[:nd]), (ckv[nd:], kpe[nd:]))
+    h = core.rmsnorm_apply(params["final_norm"], h, eps)
+    return unembed(params, h), {
+        "ckv": jnp.concatenate([cd, cm]), "kpe": jnp.concatenate([pd, pm]),
+        "experts_routed": cache["experts_routed"] + jnp.sum(n)}

@@ -16,6 +16,11 @@ Three execution paths, all numerically interchangeable:
 Decode (single new token vs a long KV cache) uses ``decode_attention`` /
 ``sharded_decode_attention`` (flash-decode style log-sum-exp combine across
 sequence shards, expressed with shard_map + psum/pmax).
+
+Multi-head latent attention (``mla_*``, DeepSeek-V2 arXiv:2405.04434 §2.1)
+caches one normalised latent and one shared rope key per position;
+``mla_full`` expands K and V from the latent, ``mla_decode`` absorbs the
+latent's up-projection into the query and the output instead.
 """
 from __future__ import annotations
 
@@ -365,3 +370,100 @@ def sharded_decode_attention(mesh, q, k, v, cur_len, *, kv_axes=("model",),
         in_specs=(q_spec, kv_spec, kv_spec, new_spec, new_spec, P(), P()),
         out_specs=(q_spec, kv_spec, kv_spec), check_vma=False)
     return fn(q, k, v, k_new, v_new, cur_len, valid_len)
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention
+# ---------------------------------------------------------------------------
+
+def mla_init(key, d_model: int, n_heads: int, kv_lora_rank: int, nope: int,
+             rope_dim: int, v_dim: int, dtype) -> core.Params:
+    """wq D->H*(nope+rope); wkva D->latent+rope; the latent's RMSNorm;
+    wkvb latent->H*(nope+v); wo H*v->D."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    R = kv_lora_rank
+    return {
+        "wq": core.dense_init(kq, (d_model, n_heads, nope + rope_dim), dtype,
+                              fan_in=d_model),
+        "wkva": core.dense_init(ka, (d_model, R + rope_dim), dtype,
+                                fan_in=d_model),
+        "kv_norm": core.rmsnorm_init(R, dtype),
+        "wkvb": core.dense_init(kb, (R, n_heads, nope + v_dim), dtype,
+                                fan_in=R),
+        "wo": core.dense_init(ko, (n_heads, v_dim, d_model), dtype,
+                              fan_in=n_heads * v_dim),
+    }
+
+
+def mla_query(params: core.Params, h, positions, theta, nope: int):
+    """h (B,S,D) -> (q_nope (B,S,H,nope), q_pe (B,S,H,rope) rotated)."""
+    q = jnp.einsum("bsd,dhk->bshk", h, params["wq"].astype(h.dtype))
+    return q[..., :nope], rope(q[..., nope:], positions, theta)
+
+
+def mla_latent(params: core.Params, h, positions, theta, eps: float):
+    """h (B,S,D) -> what a position caches: the normalised latent (B,S,R)
+    and the rope key all heads share (B,S,rope), rotated."""
+    R = params["kv_norm"]["scale"].shape[0]
+    kva = h @ params["wkva"].astype(h.dtype)
+    c = core.rmsnorm_apply(params["kv_norm"], kva[..., :R], eps)
+    k_pe = rope(kva[..., None, R:], positions, theta)[..., 0, :]
+    return c, k_pe
+
+
+def _mla_scale(params: core.Params) -> float:
+    """1/sqrt(nope + rope): the width of a query head."""
+    return 1.0 / math.sqrt(params["wq"].shape[-1])
+
+
+def mla_full(params: core.Params, h, theta, eps: float, nope: int):
+    """Causal MLA over whole sequences, K and V expanded from the latent.
+    h: (B,S,D) at positions 0..S-1.  Returns (out (B,S,D), latent, k_pe)."""
+    S = h.shape[1]
+    pos = jnp.arange(S)[None, :]
+    q_nope, q_pe = mla_query(params, h, pos, theta, nope)
+    c, k_pe = mla_latent(params, h, pos, theta, eps)
+    kv = jnp.einsum("bsr,rhk->bshk", c, params["wkvb"].astype(c.dtype))
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    s = (jnp.einsum("bqhk,bshk->bhqs", q_nope, k_nope,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bqhk,bsk->bhqs", q_pe, k_pe,
+                      preferred_element_type=jnp.float32))
+    s = s * _mla_scale(params) + _mask_bias(
+        jnp.arange(S), jnp.arange(S), causal=True, window=None)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqs,bshk->bqhk", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32).astype(h.dtype)
+    out = jnp.einsum("bqhk,hkd->bqd", o, params["wo"].astype(h.dtype))
+    return out, c, k_pe
+
+
+def mla_decode(params: core.Params, h, c_cache, pe_cache, cur_len, theta,
+               eps: float, nope: int):
+    """One token a row at position `cur_len` against the latent cache.
+    h: (B,D); c_cache: (B,T,R); pe_cache: (B,T,rope).  Writes the row's
+    latent and rope key at `cur_len`, then attends positions <= cur_len in
+    the latent space: Wkvb's key part is absorbed into the query and its
+    value part applied after the weighted sum, so no K or V is expanded.
+    Returns (out (B,D), c_cache, pe_cache)."""
+    pos = jnp.full((1, 1), cur_len)
+    q_nope, q_pe = mla_query(params, h[:, None], pos, theta, nope)
+    c, k_pe = mla_latent(params, h[:, None], pos, theta, eps)
+    c_cache = jax.lax.dynamic_update_slice_in_dim(
+        c_cache, c.astype(c_cache.dtype), cur_len, axis=1)
+    pe_cache = jax.lax.dynamic_update_slice_in_dim(
+        pe_cache, k_pe.astype(pe_cache.dtype), cur_len, axis=1)
+    wkvb = params["wkvb"].astype(h.dtype)
+    q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], wkvb[..., :nope])
+    s = (jnp.einsum("bhr,btr->bht", q_lat, c_cache,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhk,btk->bht", q_pe[:, 0], pe_cache,
+                      preferred_element_type=jnp.float32))
+    s = s * _mla_scale(params)
+    ok = jnp.arange(c_cache.shape[1]) <= cur_len
+    p = jax.nn.softmax(jnp.where(ok[None, None, :], s, NEG_INF), axis=-1)
+    o_lat = jnp.einsum("bht,btr->bhr", p, c_cache,
+                       preferred_element_type=jnp.float32)
+    o = jnp.einsum("bhr,rhk->bhk", o_lat.astype(h.dtype), wkvb[..., nope:])
+    out = jnp.einsum("bhk,hkd->bd", o, params["wo"].astype(h.dtype))
+    return out, c_cache, pe_cache

@@ -14,6 +14,10 @@ train_4k scale.
 
 ``moe_apply_dense`` is the small pure-jnp oracle (computes every expert for
 every token) used by unit/property tests.
+
+``routed_apply`` is the DeepSeek-V3 layer (Moonlight): sigmoid scores with a
+correction bias for selection, shared experts, and dropless grouped matmuls
+(``lax.ragged_dot``) on one device.
 """
 from __future__ import annotations
 
@@ -170,3 +174,84 @@ def moe_apply_sharded(params: core.Params, x: jnp.ndarray, *, mesh,
         out_specs=(x_spec, P()),
         check_vma=False)
     return fn(x, params["router"], params["wi"], params["wg"], params["wo"])
+
+
+# ---------------------------------------------------------------------------
+# dropless routed experts with shared experts (DeepSeek-V3 / Moonlight)
+# ---------------------------------------------------------------------------
+
+def routed_init(key, d_model: int, d_ff: int, n_experts: int, n_shared: int,
+                dtype) -> core.Params:
+    """`moe_init`'s experts and router, the router's correction bias, and
+    the shared experts as one SwiGLU of their summed width."""
+    k1, k2 = jax.random.split(key)
+    p = moe_init(k1, d_model, d_ff, n_experts, dtype)
+    p["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
+    p["shared_mlp"] = core.mlp_init(k2, d_model, n_shared * d_ff, dtype)
+    return p
+
+
+def route_sigmoid(x_flat: jnp.ndarray, router_w, bias, top_k: int,
+                  scaling: float, norm_topk: bool):
+    """x_flat: (T, D) -> gate weights (T,k) f32, expert ids (T,k) i32.
+
+    Float32 sigmoid scores; the top k of score + bias are selected (the
+    bias steers selection only); the weights are the selected scores,
+    normalised over the k when `norm_topk`, times `scaling`."""
+    logits = jnp.matmul(x_flat.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, top_i = jax.lax.top_k(scores + bias, top_k)
+    w = jnp.take_along_axis(scores, top_i, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scaling, top_i
+
+
+def routed_apply(params: core.Params, x: jnp.ndarray, *, top_k: int,
+                 scaling: float, norm_topk: bool, first=0):
+    """Routed experts plus shared experts.  x: (B,S,D) -> (y (B,S,D), the
+    number of distinct experts the router selected, int32).
+
+    The experts' matrices `wi`, `wg`, `wo` may hold more experts than the
+    router's E (G, D, F): this layer's are groups `first`..`first`+E-1.
+    So a stack of layers can hand every layer's experts as they lie in
+    memory, with the layer axis merged into the expert axis, and no
+    layer's slice is copied out for the kernel.
+
+    Dropless: the T*k assignments are sorted by expert and each expert's
+    rows go through grouped matmuls (`lax.ragged_dot`), so every token gets
+    all k of its experts at any skew and an expert computes only the rows
+    routed to it.  On the TPU `ragged_dot` lowers to a grouped-matmul
+    kernel that visits only the groups that have rows, so an expert no
+    token chose is not read.  The shared experts see every token and are
+    added unweighted."""
+    B, S, D = x.shape
+    xf = x.reshape(-1, D)
+    w, top_i = route_sigmoid(xf, params["router"], params["router_bias"],
+                             top_k, scaling, norm_topk)
+    flat = top_i.reshape(-1)
+    n = flat.shape[0]
+    order = jnp.argsort(flat)
+    rows = order // top_k                        # token of each sorted row
+    sizes = jnp.zeros((params["wi"].shape[0],), jnp.int32).at[
+        first + flat].add(1)
+    # The TPU's grouped-matmul kernel takes rows in multiples of 8; for
+    # other counts XLA multiplies densely by every group's matrix.  The
+    # extra rows repeat the last row, in its group, so no other expert is
+    # read, and their outputs are dropped.
+    pad = -n % 8
+    if pad:
+        rows = jnp.pad(rows, (0, pad), mode="edge")
+        sizes = sizes.at[first + flat[order[-1]]].add(pad)
+    dt = x.dtype
+    xs = xf[rows]
+    h = jax.lax.ragged_dot(xs, params["wi"].astype(dt), sizes)
+    g = jax.lax.ragged_dot(xs, params["wg"].astype(dt), sizes)
+    o = jax.lax.ragged_dot(jax.nn.silu(g) * h, params["wo"].astype(dt),
+                           sizes)[:n]
+    y = jnp.zeros(xf.shape, jnp.float32).at[rows[:n]].add(
+        o.astype(jnp.float32) * w.reshape(-1)[order][:, None])
+    y = y + core.mlp_apply(params["shared_mlp"], xf).astype(jnp.float32)
+    return y.astype(dt).reshape(B, S, D), jnp.sum(sizes > 0, dtype=jnp.int32)

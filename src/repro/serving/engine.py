@@ -55,6 +55,10 @@ class ServeStats:
     prefills: int = 0
     decode_steps: int = 0
     tokens_out: int = 0
+    # distinct experts the router selected, summed over routed layers and
+    # model steps; read from the cache's counter once a batch, where the
+    # model's cache carries one
+    experts_routed: int = 0
 
 
 class Server:
@@ -122,5 +126,7 @@ class Server:
                 self.stats.decode_steps += 1
                 pos += 1
                 next_tok = jnp.argmax(logits, axis=-1)
+            if "experts_routed" in cache:
+                self.stats.experts_routed += int(cache["experts_routed"])
             finished.extend(batch)
         return finished

@@ -69,7 +69,8 @@ def test_train_step_no_nans(arch):
 
 @pytest.mark.parametrize("arch", ["granite-3-2b", "gemma3-4b",
                                   "mamba2-2.7b", "zamba2-1.2b",
-                                  "moonshot-v1-16b-a3b", "whisper-medium"])
+                                  "moonshot-v1-16b-a3b", "whisper-medium",
+                                  "moonlight-16b-a3b"])
 def test_decode_matches_teacher_forcing(arch):
     cfg, model = registry.get(arch, smoke=True)
     params = model.init(jax.random.PRNGKey(0), cfg)
@@ -81,7 +82,10 @@ def test_decode_matches_teacher_forcing(arch):
                                    (B, cfg.audio_frames, cfg.d_model))
         kw["frames"] = frames
     h, _ = model.forward(params, cfg, tokens, remat=False, **kw)
-    full = core.unembed_logits(params["embed"]["table"], h)
+    if "lm_head" in params:                              # untied head
+        full = h @ params["lm_head"]
+    else:
+        full = core.unembed_logits(params["embed"]["table"], h)
 
     cache = model.init_cache(cfg, B, S, jnp.float32)
     if cfg.family == "encdec":
