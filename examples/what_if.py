@@ -58,7 +58,8 @@ print(f"\n{st.queries} queries in {st.batches} batched executions: "
       f"mean {st.mean_ms:.0f} ms")
 
 # every daysim cache tier in one snapshot: scenario-row tables, host
-# assemblies, value-keyed pipelines, compiled executables; then the host
+# assemblies and their structure skeletons, value-keyed pipelines,
+# compiled executables; then the host
 # phases' counters and the host<->device transfers
 stats = daysim.cache_stats()
 host_phases = stats.pop("phases")

@@ -876,9 +876,12 @@ _PIPELINES: dict = {}
 _PIPELINES_MAX = 32
 _ASSEMBLIES: dict = {}
 _ASSEMBLIES_MAX = 64
+_SKELETONS: dict = {}
+_SKELETONS_MAX = 16
 EXEC_STATS = {"hits": 0, "misses": 0, "traces": 0}
 PIPELINE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
 ASSEMBLY_STATS = {"hits": 0, "misses": 0, "evictions": 0}
+SKELETON_STATS = {"hits": 0, "misses": 0, "evictions": 0}
 
 
 def _cached_executable(key, build):
@@ -896,9 +899,11 @@ def clear_exec_cache() -> None:
     _EXEC_CACHE.clear()
     _PIPELINES.clear()
     _ASSEMBLIES.clear()
+    _SKELETONS.clear()
     EXEC_STATS.update(hits=0, misses=0, traces=0)
     PIPELINE_STATS.update(hits=0, misses=0, evictions=0)
     ASSEMBLY_STATS.update(hits=0, misses=0, evictions=0)
+    SKELETON_STATS.update(hits=0, misses=0, evictions=0)
 
 
 def cache_stats() -> dict:
@@ -906,7 +911,9 @@ def cache_stats() -> dict:
     trace) counters plus the live entry count, keyed by tier.
 
     ``rows`` is the `_ROW_CACHE` row-evaluation cache, ``assemblies``
-    the value-keyed host-assembly cache, ``pipelines`` the value-keyed
+    the value-keyed host-assembly cache, ``skeletons`` the
+    structure-keyed cache of their value-free halves (a new what-if with
+    known structure hits it), ``pipelines`` the value-keyed
     assembled-pipeline cache, and ``exec`` the signature-keyed compiled
     executable cache (whose ``traces`` counter the zero-retrace tests
     pin).  The FIFO tiers evict silently during queries; this accessor
@@ -919,6 +926,7 @@ def cache_stats() -> dict:
     return {
         "rows": {**CACHE_STATS, "size": len(_ROW_CACHE)},
         "assemblies": {**ASSEMBLY_STATS, "size": len(_ASSEMBLIES)},
+        "skeletons": {**SKELETON_STATS, "size": len(_SKELETONS)},
         "pipelines": {**PIPELINE_STATS, "size": len(_PIPELINES)},
         "exec": {**EXEC_STATS, "size": len(_EXEC_CACHE)},
         "phases": phases.snapshot(),
@@ -1464,15 +1472,17 @@ def _design_key(d: dict) -> tuple:
 @dataclass
 class _Assembly:
     """Host half of one fully-valued fused query, padded to canonical
-    bucket shapes: numpy masters for the value-level inputs (`dyn`),
-    numpy gather indices / step data (`ix`), and the static signature
-    the compiled executable is keyed by, with both trees packed into
-    one buffer per dtype.  Backend-independent — the single-query path
-    pushes the packed `ix` to the device once (`_Pipeline`), the batch
-    path stacks K assemblies' buffers along a leading query axis."""
+    bucket shapes: the value-level inputs (`dyn`), the gather indices /
+    step data (`ix`), and the static signature the compiled executable
+    is keyed by, with both trees packed into one buffer per dtype (the
+    trees are views into `packed`; its int32 buffers are its
+    `_Skeleton`'s, read-only).  Backend-independent — the single-query
+    path pushes the packed `ix` to the device once (`_Pipeline`), the
+    batch path stacks K assemblies' buffers along a leading query
+    axis."""
     combos: list
     skipped: list
-    dyn: dict               # numpy masters (incl. combo_w), bucketed
+    dyn: dict               # numpy views (incl. combo_w), bucketed
     ix: dict                # numpy gather indices / step data, bucketed
     plats: tuple            # platform specs, row-stage order
     sig: tuple              # static shape signature (no backend)
@@ -1482,6 +1492,22 @@ class _Assembly:
     dt_s: float
     layouts: tuple          # (`dyn`, `ix`) `_Layout`s, fixed by `sig`
     packed: tuple           # (`dyn`, `ix`) packed by `layouts`
+
+
+@dataclass(frozen=True, eq=False)
+class _Skeleton:
+    """Structure half of an assembly, shared by every query of the same
+    structure (`_assemble_query`'s `skey`): the packed buffers with each
+    platform group's scenario rows (`vec`), the per-combo step columns,
+    gather indices and level multipliers written in and every value
+    leaf zero, with the signature and layouts they follow.  Its arrays
+    are read-only; a query writes its values into its own copy."""
+    plats: tuple
+    sig: tuple
+    layouts: tuple          # (`dyn`, `ix`) `_Layout`s, fixed by `sig`
+    packed: tuple           # (`dyn`, `ix`) packed, values zero
+    seg_charge: np.ndarray  # (n_b, T) dock charge before the puck split
+    valid: np.ndarray       # (n_b, T) bool, the steps inside each day
 
 
 @dataclass
@@ -1597,7 +1623,6 @@ def _build_fused_batch(plats: tuple, backend: str):
 TRANSFER_STATS = {"h2d_calls": 0, "h2d_bytes": 0, "d2h_calls": 0,
                   "d2h_bytes": 0}
 _TRANSFER_LOCK = threading.Lock()       # concurrent `run()`s count here
-_LAYOUTS: dict = {}
 # a fused program's summary fields in packed order; the two flags cross
 # the boundary as float32 0/1
 _SUMMARY_KEYS = ("day_hours", "time_to_empty_h", "end_soc", "end_soc_puck",
@@ -1697,6 +1722,165 @@ def _split_summary(out: np.ndarray) -> dict:
             for j, k in enumerate(_SUMMARY_KEYS)}
 
 
+def _pad_rows(a, n_b: int) -> np.ndarray:
+    """`a` with rows added up to `n_b`, each a copy of row 0: the bucket
+    padding of the combo axis."""
+    a = np.asarray(a)
+    if len(a) == n_b:
+        return a
+    return np.concatenate([a, np.repeat(a[:1], n_b - len(a), 0)])
+
+
+def _read_only(tree):
+    """Mark every array of `tree` read-only: it is shared by every
+    assembly built on the same skeleton."""
+    for x in jax.tree_util.tree_leaves(tree):
+        if isinstance(x, np.ndarray):
+            x.flags.writeable = False
+    return tree
+
+
+def _theta_of(plat: PlatformSpec, theta) -> dict:
+    th = plat.theta_dict()
+    if theta:
+        th.update(theta)
+    return th
+
+
+def _build_skeleton(groups, combos, dt_s, theta, results_dir) -> _Skeleton:
+    """Build the structure half of an assembly: the scenario rows of each
+    platform group, the per-combo step columns, the signature, the
+    layouts, and the packed buffers with every structure leaf written
+    in and every value leaf zero."""
+    T = max(cb.schedule.n_steps(dt_s) for cb in combos)
+    L = max(cb.policy.n_levels for cb in combos)
+    rr = offload.stream_rates(results_dir)
+    grp_dyn, theta_keys, row_counts = [], [], []
+    lvl_row, seg_of, steady_of = [], [], []
+    ambs, acts, vals, chgs, amults = [], [], [], [], []
+    base = 0
+    for plat, grp in groups:
+        rows, slices = [], []
+        for cb in grp:
+            slices.append(_combo_rows(cb, rows))
+        sset = ScenarioSet.build(rows, primitives=plat.primitives)
+        scenarios._validate(plat, sset)
+        r_b = bucket_size(len(rows)) if rows else 0
+        sset = sset.pad(r_b)
+        p_base, p_wan = _puck_coeffs(plat)
+        th = _theta_of(plat, theta)
+        grp_dyn.append({
+            "vec": {"placement": sset.placement,
+                    "compression": sset.compression,
+                    "fps_scale": sset.fps_scale,
+                    "mcs_tier": sset.mcs_tier,
+                    "upload_duty": sset.upload_duty,
+                    "brightness": sset.brightness},
+            "theta": {k: np.float32(0.0) for k in th},
+            "p_base": np.float32(p_base), "p_wan": np.float32(p_wan)})
+        theta_keys.append(tuple(sorted(th)))
+        row_counts.append(r_b)
+        for cb, (start, steady_i) in zip(grp, slices):
+            segs = cb.schedule.segments
+            n_seg, n_lvl = len(segs), cb.policy.n_levels
+            seg_steps = [max(1, round(s.hours * 3600.0 / dt_s))
+                         for s in segs]
+            seg_idx = np.repeat(np.arange(n_seg), seg_steps)
+            t = len(seg_idx)
+            so = np.full(T, n_seg - 1, np.int32)   # pad: last segment
+            so[:t] = seg_idx
+            seg_of.append(so)
+            lv = np.minimum(np.arange(L), n_lvl - 1)  # pad: last level
+            lvl_row.append((base + start + lv * n_seg).astype(np.int32))
+            steady_of.append(base + steady_i)
+            amb = np.full(T, segs[-1].ambient_c, np.float32)
+            amb[:t] = np.asarray([s.ambient_c for s in segs],
+                                 np.float32)[seg_idx]
+            ambs.append(amb)
+            act = np.zeros(T, np.float32)
+            act[:t] = np.asarray([s.active for s in segs],
+                                 np.float32)[seg_idx]
+            acts.append(act)
+            val = np.zeros(T, np.float32)
+            val[:t] = 1.0
+            vals.append(val)
+            chg = np.zeros(T, np.float32)
+            chg[:t] = np.asarray([s.charge_mw for s in segs],
+                                 np.float32)[seg_idx]
+            chgs.append(chg)
+            amult = np.ones(L, np.float32)
+            for l in range(1, n_lvl):
+                amult[l:] = cb.policy.action(l).active_mult
+            amults.append(amult)
+        base += r_b
+
+    n_real = len(combos)
+    n_b = bucket_size(n_real)
+    combo_w = np.zeros(n_b, np.float32)
+    combo_w[:n_real] = 1.0
+    const_keys = tuple(_combo_const(combos[0], dt_s, 0.0, 0.0))
+    dyn = {"groups": tuple(grp_dyn),
+           "rates": np.asarray(rr["tok_per_cap"], np.float32),
+           "gate": np.float32(0.0),
+           "act_mult": _pad_rows(np.stack(amults), n_b),
+           "const": {k: np.zeros(n_b, np.float32) for k in const_keys},
+           "combo_w": combo_w,
+           "dt_s": np.float32(dt_s)}
+    ix = {"lvl_row": _pad_rows(np.stack(lvl_row), n_b),
+          "seg_of": _pad_rows(np.stack(seg_of), n_b),
+          "steady_of": _pad_rows(np.asarray(steady_of, np.int32), n_b),
+          "ambient": _pad_rows(np.stack(ambs), n_b),
+          "active": _pad_rows(np.stack(acts), n_b),
+          "valid": _pad_rows(np.stack(vals), n_b),
+          "charge": np.zeros((n_b, T), np.float32),
+          "charge_p": np.zeros((n_b, T), np.float32)}
+    plats = tuple(plat for plat, _ in groups)
+    sig = ("fused", plats, tuple(theta_keys), tuple(row_counts),
+           n_b, T, L, len(rr["tok_per_cap"]))
+    layouts = (_layout_of(dyn), _layout_of(ix))
+    return _Skeleton(plats, sig, layouts,
+                     (_read_only(_pack(layouts[0], dyn)),
+                      _read_only(_pack(layouts[1], ix))),
+                     _read_only(_pad_rows(np.stack(chgs), n_b)),
+                     _read_only(ix["valid"] > 0.0))
+
+
+def _with_values(skel: _Skeleton, combos, dt_s, n_users, standby_mw,
+                 shutdown_c, theta) -> tuple:
+    """One query's packed buffers and their trees: a copy of the
+    skeleton's float32 buffers (every value is float32) with this
+    query's values written in (scan constants, the charge split between
+    glasses and puck, the gate and theta); the int32 buffers are the
+    skeleton's own.  The trees are views into the buffers."""
+    packed = tuple(tuple(b.copy() if b.dtype == np.float32 else b
+                         for b in bufs) for bufs in skel.packed)
+    dyn, ix = (_unpack(lay, bufs) for lay, bufs in zip(skel.layouts, packed))
+    n_real = len(combos)
+    consts = [_combo_const(cb, dt_s, standby_mw, shutdown_c)
+              for cb in combos]
+    for k, col in dyn["const"].items():
+        col[:n_real] = np.asarray([c[k] for c in consts], np.float32)
+        col[n_real:] = col[0]
+    dyn["gate"][...] = np.float32(n_users)
+    for plat, grp in zip(skel.plats, dyn["groups"]):
+        th = _theta_of(plat, theta)
+        for k, v in grp["theta"].items():
+            v[...] = np.float32(th[k])
+    share = []
+    for cb in combos:
+        cap_g = cb.battery.capacity_mwh
+        cap_p = (cb.puck.battery.capacity_mwh
+                 if cb.puck is not None else 0.0)
+        share.append(cap_g / (cap_g + cap_p) if cap_p else 1.0)
+    share = _pad_rows(np.asarray(share), len(skel.seg_charge))[:, None]
+    # steps past a day's end stay 0 whatever the share, as they were
+    np.multiply(skel.seg_charge, share.astype(np.float32),
+                out=ix["charge"], where=skel.valid)
+    np.multiply(skel.seg_charge, (1.0 - share).astype(np.float32),
+                out=ix["charge_p"], where=skel.valid)
+    return dyn, ix, packed
+
+
 def _assemble_query(platforms, designs, schedules, policies, dt_s,
                     n_users, standby_mw, battery, thermal, theta,
                     results_dir, shutdown_c) -> _Assembly:
@@ -1708,18 +1892,31 @@ def _assemble_query(platforms, designs, schedules, policies, dt_s,
     the compiled executable — depends on the bucket, not the raw axis
     size.  Assemblies are value-keyed in the `_ASSEMBLIES` FIFO so
     repeated identical queries (and batch items) skip the host build
-    entirely."""
+    entirely.  A new query whose structure is known takes its scenario
+    rows, step columns and packed structure leaves from the
+    structure-keyed `_SKELETONS` FIFO and writes only its values (trip
+    points, battery and thermal constants, the charge split, gate,
+    theta) into a copy of the packed buffers; the bytes equal a cold
+    build's."""
+    schedules = tuple(_resolve(s, get_schedule, DaySchedule)
+                      for s in schedules)
+    policies = tuple(_resolve(p, get_policy, ThrottlePolicy)
+                     for p in policies)
     groups, skipped = _enumerate_combos(platforms, designs, schedules,
                                         policies, battery, thermal)
     combos = [cb for _, grp in groups for cb in grp]
     if not combos:
         raise ValueError("no runnable (platform, design) combos")
-    key = (tuple((plat, tuple((_design_key(cb.design), cb.schedule,
-                               cb.policy, cb.battery, cb.thermal)
-                              for cb in grp))
-                 for plat, grp in groups),
-           float(dt_s), float(n_users), float(standby_mw),
-           _theta_key(theta), str(results_dir), float(shutdown_c))
+    # the grid's axes fix its combos (designs a platform cannot run are
+    # skipped the same way every time), so both keys are built from the
+    # axes, not from every combo
+    plats = tuple(plat for plat, _ in groups)
+    design_keys = tuple(_design_key(d) for d in designs)
+    key = (plats, design_keys, schedules, policies,
+           tuple(cb.battery for _, grp in groups for cb in grp[:1]),
+           combos[0].thermal, float(dt_s), float(n_users),
+           float(standby_mw), _theta_key(theta), str(results_dir),
+           float(shutdown_c))
     asm = _ASSEMBLIES.get(key)
     if asm is not None:
         ASSEMBLY_STATS["hits"] += 1
@@ -1727,119 +1924,28 @@ def _assemble_query(platforms, designs, schedules, policies, dt_s,
     ASSEMBLY_STATS["misses"] += 1
 
     with phases.phase("daysim.assemble"):
-        T = max(cb.schedule.n_steps(dt_s) for cb in combos)
-        L = max(cb.policy.n_levels for cb in combos)
-        rr = offload.stream_rates(results_dir)
-        grp_dyn, theta_keys, row_counts = [], [], []
-        lvl_row, seg_of, steady_of = [], [], []
-        ambs, acts, vals, chgs, chgs_p, amults, consts = \
-            [], [], [], [], [], [], []
-        base = 0
-        for plat, grp in groups:
-            rows, slices = [], []
-            for cb in grp:
-                slices.append(_combo_rows(cb, rows))
-            sset = ScenarioSet.build(rows, primitives=plat.primitives)
-            scenarios._validate(plat, sset)
-            r_b = bucket_size(len(rows)) if rows else 0
-            sset = sset.pad(r_b)
-            th = plat.theta_dict()
-            if theta:
-                th.update(theta)
-            p_base, p_wan = _puck_coeffs(plat)
-            grp_dyn.append({
-                "vec": {"placement": sset.placement,
-                        "compression": sset.compression,
-                        "fps_scale": sset.fps_scale,
-                        "mcs_tier": sset.mcs_tier,
-                        "upload_duty": sset.upload_duty,
-                        "brightness": sset.brightness},
-                "theta": {k: np.float32(v) for k, v in th.items()},
-                "p_base": np.float32(p_base), "p_wan": np.float32(p_wan)})
-            theta_keys.append(tuple(sorted(th)))
-            row_counts.append(r_b)
-            for cb, (start, steady_i) in zip(grp, slices):
-                segs = cb.schedule.segments
-                n_seg, n_lvl = len(segs), cb.policy.n_levels
-                seg_steps = [max(1, round(s.hours * 3600.0 / dt_s))
-                             for s in segs]
-                seg_idx = np.repeat(np.arange(n_seg), seg_steps)
-                t = len(seg_idx)
-                so = np.full(T, n_seg - 1, np.int32)   # pad: last segment
-                so[:t] = seg_idx
-                seg_of.append(so)
-                lv = np.minimum(np.arange(L), n_lvl - 1)  # pad: last level
-                lvl_row.append((base + start + lv * n_seg).astype(np.int32))
-                steady_of.append(base + steady_i)
-                amb = np.full(T, segs[-1].ambient_c, np.float32)
-                amb[:t] = np.asarray([s.ambient_c for s in segs],
-                                     np.float32)[seg_idx]
-                ambs.append(amb)
-                act = np.zeros(T, np.float32)
-                act[:t] = np.asarray([s.active for s in segs],
-                                     np.float32)[seg_idx]
-                acts.append(act)
-                val = np.zeros(T, np.float32)
-                val[:t] = 1.0
-                vals.append(val)
-                cap_g = cb.battery.capacity_mwh
-                cap_p = (cb.puck.battery.capacity_mwh
-                         if cb.puck is not None else 0.0)
-                share_g = cap_g / (cap_g + cap_p) if cap_p else 1.0
-                seg_charge = np.asarray([s.charge_mw for s in segs],
-                                        np.float32)[seg_idx]
-                chg = np.zeros(T, np.float32)
-                chg_p = np.zeros(T, np.float32)
-                chg[:t] = seg_charge * np.float32(share_g)
-                chg_p[:t] = seg_charge * np.float32(1.0 - share_g)
-                chgs.append(chg)
-                chgs_p.append(chg_p)
-                amult = np.ones(L, np.float32)
-                for l in range(1, n_lvl):
-                    amult[l:] = cb.policy.action(l).active_mult
-                amults.append(amult)
-                consts.append(_combo_const(cb, dt_s, standby_mw, shutdown_c))
-            base += r_b
-
-        n_real = len(combos)
-        n_b = bucket_size(n_real)
-
-        def _pad_n(a):
-            a = np.asarray(a)
-            if n_b == n_real:
-                return a
-            return np.concatenate([a, np.repeat(a[:1], n_b - n_real, 0)])
-
-        combo_w = np.zeros(n_b, np.float32)
-        combo_w[:n_real] = 1.0
-        dyn = {"groups": tuple(grp_dyn),
-               "rates": np.asarray(rr["tok_per_cap"], np.float32),
-               "gate": np.float32(n_users),
-               "act_mult": _pad_n(np.stack(amults)),
-               "const": {k: _pad_n(np.asarray([c[k] for c in consts],
-                                              np.float32))
-                         for k in consts[0]},
-               "combo_w": combo_w,
-               "dt_s": np.float32(dt_s)}
-        ix = {"lvl_row": _pad_n(np.stack(lvl_row)),
-              "seg_of": _pad_n(np.stack(seg_of)),
-              "steady_of": _pad_n(np.asarray(steady_of, np.int32)),
-              "ambient": _pad_n(np.stack(ambs)),
-              "active": _pad_n(np.stack(acts)),
-              "valid": _pad_n(np.stack(vals)),
-              "charge": _pad_n(np.stack(chgs)),
-              "charge_p": _pad_n(np.stack(chgs_p))}
-
-        plats = tuple(plat for plat, _ in groups)
-        sig = ("fused", plats, tuple(theta_keys), tuple(row_counts),
-               n_b, T, L, len(rr["tok_per_cap"]))
-        # the signature fixes every leaf's shape, so the layout too
-        layouts = _LAYOUTS.get(sig)
-        if layouts is None:
-            layouts = _LAYOUTS[sig] = (_layout_of(dyn), _layout_of(ix))
-        asm = _Assembly(combos, skipped, dyn, ix, plats, sig, key, n_real,
-                        float(n_users), float(dt_s), layouts,
-                        (_pack(layouts[0], dyn), _pack(layouts[1], ix)))
+        # what fixes the structure: the platforms, the theta keys, the
+        # designs, schedules and policy actions (not their trip points),
+        # `dt_s` and `results_dir`; the step and level counts follow
+        skey = (plats, design_keys, schedules,
+                tuple(p.actions for p in policies),
+                tuple(sorted(theta)) if theta else (), float(dt_s),
+                str(results_dir))
+        skel = _SKELETONS.get(skey)
+        if skel is None:
+            SKELETON_STATS["misses"] += 1
+            skel = _SKELETONS[skey] = _build_skeleton(
+                groups, combos, dt_s, theta, results_dir)
+            while len(_SKELETONS) > _SKELETONS_MAX:
+                del _SKELETONS[next(iter(_SKELETONS))]
+                SKELETON_STATS["evictions"] += 1
+        else:
+            SKELETON_STATS["hits"] += 1
+        dyn, ix, packed = _with_values(skel, combos, dt_s, n_users,
+                                       standby_mw, shutdown_c, theta)
+        asm = _Assembly(combos, skipped, dyn, ix, skel.plats, skel.sig,
+                        key, len(combos), float(n_users), float(dt_s),
+                        skel.layouts, packed)
         _ASSEMBLIES[key] = asm
         while len(_ASSEMBLIES) > _ASSEMBLIES_MAX:
             del _ASSEMBLIES[next(iter(_ASSEMBLIES))]
@@ -1852,10 +1958,12 @@ def _fused_pipeline(platforms, designs, schedules, policies, dt_s,
                     results_dir, shutdown_c, backend) -> _Pipeline:
     """Assemble (or fetch) the fused pipeline for one fully-valued query.
 
-    Three cache tiers back the interactive twin: `_PIPELINES` (FIFO,
+    Four cache tiers back the interactive twin: `_PIPELINES` (FIFO,
     value-keyed) returns the whole assembled pipeline — repeated
     identical queries skip even the host-side index build —
-    `_ASSEMBLIES` caches the backend-independent host half, and
+    `_ASSEMBLIES` caches the backend-independent host half,
+    `_SKELETONS` (structure-keyed) the part of it a new what-if of
+    known structure reuses, and
     `_EXEC_CACHE` (signature-keyed, bucket-padded shapes) shares the
     compiled program across queries that differ only in VALUES (policy
     thresholds, design knobs, schedule ambients) or that land in the

@@ -157,6 +157,14 @@ class PlatformSpec:
     def __len__(self) -> int:
         return len(self.components)
 
+    def __hash__(self) -> int:
+        # The specs key the day simulator's caches on every what-if, and
+        # the generated hash walks every component's load rules.  This
+        # one reads the small fields only: equal specs still hash equal,
+        # and specs that differ only in components fall back to `==`.
+        return hash((self.name, self.primitives, self.theta, self.raw_mbps,
+                     self.ip_rates, self.companion, len(self.components)))
+
     # -- variants -----------------------------------------------------------
     def variant(self, name: str, drop: Iterable[str] = (),
                 add: Iterable[ComponentSpec] = (),

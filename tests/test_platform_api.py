@@ -77,6 +77,20 @@ def test_platform_roundtrip_serialization(plat):
         np.asarray(scenarios.total_mw(plat, sset)))
 
 
+def test_hash_agrees_with_equality(plat):
+    """Equal specs hash equal; a spec that differs only in its components,
+    under the same name, is still a key of its own."""
+    import dataclasses
+    rebuilt = PlatformSpec.from_dict(json.loads(json.dumps(plat.to_dict())))
+    assert rebuilt is not plat and hash(rebuilt) == hash(plat)
+    lighter = dataclasses.replace(
+        plat, components=plat.components[:-1] + (dataclasses.replace(
+            plat.components[-1], name=plat.components[-1].name + "_b"),))
+    assert lighter != plat
+    keys = {plat: "a", lighter: "b"}
+    assert keys[rebuilt] == "a" and keys[lighter] == "b"
+
+
 def test_registry_lookup():
     aria2.platforms()
     assert {"aria2", "aria2_display", "aria2_capture_only"} <= \

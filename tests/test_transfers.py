@@ -2,12 +2,12 @@
 
 An assembly carries its `dyn` and `ix` trees packed into one contiguous
 buffer per dtype (`daysim._pack`, by a `_Layout` fixed by the shape
-signature); the programs unpack them on the device and return their
-summary fields stacked in one float32 array.  These tests pin that the
-packing loses no bit, that the packed programs answer bit for bit as
-the bare bodies do, and that a batch or a serial query crosses the
-boundary once each way (the ``transfers`` tier of
-`daysim.cache_stats()`)."""
+signature and kept with the assembly's skeleton); the programs unpack
+them on the device and return their summary fields stacked in one
+float32 array.  These tests pin that the packing loses no bit, that
+the packed programs answer bit for bit as the bare bodies do, and that
+a batch or a serial query crosses the boundary once each way (the
+``transfers`` tier of `daysim.cache_stats()`)."""
 from __future__ import annotations
 
 import dataclasses
@@ -52,7 +52,8 @@ def _same_leaves(got, want):
 @pytest.mark.parametrize("grid", list(GRIDS))
 def test_pack_then_unpack_gives_back_every_leaf(grid):
     asm = _assembly(**GRIDS[grid])
-    assert daysim._LAYOUTS[asm.sig] is asm.layouts
+    assert asm.layouts == (daysim._layout_of(asm.dyn),
+                           daysim._layout_of(asm.ix))
     for layout, tree, bufs in zip(asm.layouts, (asm.dyn, asm.ix),
                                   asm.packed):
         assert tuple(b.dtype for b in bufs) == layout.dtypes

@@ -1,6 +1,6 @@
 """Twin v2 serving contracts: batched queries, canonical shape
-bucketing, the persistent-compile-cache shim, and the cache-stats
-accessor.
+bucketing, the host assembly's skeleton cache, the persistent-compile-
+cache shim, and the cache-stats accessor.
 
 The load-bearing invariants:
   * batched (`what_if_many` / `day_pareto_batch`) answers are
@@ -11,7 +11,10 @@ The load-bearing invariants:
     (`EXEC_STATS["traces"]` flat);
   * concurrent threads hammering `submit()`/`run()` with mixed shapes
     serialize to the same results as serial queries, with no retraces
-    once the shapes are warm.
+    once the shapes are warm;
+  * a what-if that reuses a cached skeleton packs the same bytes, and
+    answers the same report, as a cold build of it; a structural change
+    never reuses one.
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import dataclasses
 import threading
 from pathlib import Path
 
+import jax.tree_util as jax_tree
 import numpy as np
 import pytest
 
@@ -237,8 +241,8 @@ def test_concurrent_submit_run_mixed_shapes(twin):
 
 def test_cache_stats_accessor(twin):
     stats = daysim.cache_stats()
-    assert set(stats) == {"rows", "assemblies", "pipelines", "exec",
-                          "phases", "transfers"}
+    assert set(stats) == {"rows", "assemblies", "skeletons", "pipelines",
+                          "exec", "phases", "transfers"}
     for name, tier in stats.items():
         if name not in ("phases", "transfers"):
             assert {"hits", "misses", "size"} <= set(tier)
@@ -255,6 +259,202 @@ def test_cache_stats_accessor(twin):
     assert stats["phases"]["repro.daysim.dispatch"]["calls"] >= d0 + 2
     assert stats["exec"]["size"] >= 1
     assert stats["rows"]["evictions"] >= 0
+    assert set(stats["skeletons"]) == {"hits", "misses", "evictions", "size"}
+    assert 1 <= stats["skeletons"]["size"] <= daysim._SKELETONS_MAX
+
+
+# -- skeleton cache: structure built once, values written per query -------
+
+SK_DT = DT
+SK_GOV = daysim.get_policy("thermal_governor")
+SK_BASE = {**daysim._batch_defaults(),
+           "platforms": ("aria2_display", "aria2_puck_split"),
+           "designs": daysim.DEFAULT_DESIGNS[:2],
+           # a docked day (charge split between glasses and puck) and a
+           # shorter one (padded steps)
+           "schedules": ("commuter_dock", "desk_day"),
+           "policies": ("none", SK_GOV), "dt_s": SK_DT,
+           "theta": {"eff_scale": 1.0}}
+
+
+def _battery(name: str, scale: float) -> daysim.BatterySpec:
+    bat = daysim.battery_for(name)
+    return dataclasses.replace(bat, capacity_mwh=bat.capacity_mwh * scale)
+
+
+def _puck_variant():
+    from repro.core import platform as registry
+    plat = registry.get("aria2_puck_split")
+    comp = tuple((k, v * 1.2 if k == "base_mw" else v)
+                 for k, v in plat.companion)
+    return dataclasses.replace(plat, companion=comp)
+
+
+# value-only changes: each reuses the skeleton `SK_BASE` built
+SK_VALUES = {
+    "trip_points": lambda: {"policies": ("none", dataclasses.replace(
+        SK_GOV, name="hot", temp_trip_c=41.25, soc_trip=0.09))},
+    # glasses capacity moves, the puck's does not: the charge split too
+    "capacity": lambda: {"battery": {
+        "aria2_display": _battery("aria2_display", 1.1),
+        "aria2_puck_split": _battery("aria2_puck_split", 0.85)}},
+    "theta": lambda: {"theta": {"eff_scale": 1.05}},
+    "n_users": lambda: {"n_users": 2.5e6},
+    "standby_mw": lambda: {"standby_mw": 52.0},
+}
+
+# structural changes: each must build a skeleton of its own
+SK_STRUCTURE = {
+    "compression": lambda: {"designs": (
+        dict(daysim.DEFAULT_DESIGNS[0], compression=24.0),
+        daysim.DEFAULT_DESIGNS[1])},
+    "actions": lambda: {"policies": ("none", dataclasses.replace(
+        SK_GOV, actions=(dataclasses.replace(SK_GOV.actions[0],
+                                             fps_mult=3.0),)
+        + SK_GOV.actions[1:]))},
+    "schedule": lambda: {"schedules": (
+        daysim.get_schedule("commuter_dock").with_ambient_offset(3.0),
+        "desk_day")},
+    "dt_s": lambda: {"dt_s": SK_DT / 2},
+    "platform": lambda: {"platforms": ("aria2_display", _puck_variant())},
+}
+
+
+def _clear_host_tiers():
+    """Forget every host-built assembly, skeleton and pipeline; the
+    compiled programs stay."""
+    for tier in (daysim._ASSEMBLIES, daysim._SKELETONS, daysim._PIPELINES):
+        tier.clear()
+
+
+def _skeleton_counts() -> tuple:
+    sk = daysim.cache_stats()["skeletons"]
+    return sk["hits"], sk["misses"]
+
+
+def _same_bytes(a, b) -> bool:
+    return all(x.dtype == y.dtype and x.tobytes() == y.tobytes()
+               for x, y in zip(a, b)) and len(a) == len(b)
+
+
+def _assert_same_assembly(got, want):
+    assert got.sig == want.sig and got.n_real == want.n_real
+    for g, w in zip(got.packed, want.packed):
+        assert _same_bytes(g, w)
+    for g, w in zip((got.dyn, got.ix), (want.dyn, want.ix)):
+        g_l, g_def = jax_tree.tree_flatten(g)
+        w_l, w_def = jax_tree.tree_flatten(w)
+        assert g_def == w_def
+        assert _same_bytes([np.asarray(x) for x in g_l],
+                           [np.asarray(x) for x in w_l])
+
+
+@pytest.mark.parametrize("change", list(SK_VALUES))
+def test_skeleton_hit_is_bit_identical_to_a_cold_build(change):
+    query = {**SK_BASE, **SK_VALUES[change]()}
+    _clear_host_tiers()
+    base = daysim._assemble_query(**SK_BASE)
+    h0, m0 = _skeleton_counts()
+    hit = daysim._assemble_query(**query)
+    assert _skeleton_counts() == (h0 + 1, m0)
+    # the value really changed what is packed
+    assert not _same_bytes(hit.packed[0] + hit.packed[1],
+                           base.packed[0] + base.packed[1])
+    hit_rep = daysim.day_grid_batch([query])[0]
+    _clear_host_tiers()
+    cold = daysim._assemble_query(**query)
+    assert _skeleton_counts() == (h0 + 1, m0 + 1)
+    cold_rep = daysim.day_grid_batch([query])[0]
+    _assert_same_assembly(hit, cold)
+    _assert_identical(hit_rep, cold_rep)
+    for f in ("end_soc_puck", "peak_skin_puck_c", "shutdown"):
+        assert np.array_equal(getattr(hit_rep, f), getattr(cold_rep, f))
+
+
+@pytest.mark.parametrize("change", list(SK_STRUCTURE))
+def test_structural_change_misses_and_builds_its_own_rows(change):
+    query = {**SK_BASE, **SK_STRUCTURE[change]()}
+    _clear_host_tiers()
+    base = daysim._assemble_query(**SK_BASE)
+    h0, m0 = _skeleton_counts()
+    warm = daysim._assemble_query(**query)
+    assert _skeleton_counts() == (h0, m0 + 1)
+    assert daysim.cache_stats()["skeletons"]["size"] == 2
+    if warm.sig == base.sig:
+        # same shapes: a stale skeleton would have packed base's bytes
+        assert not _same_bytes(warm.packed[0] + warm.packed[1],
+                               base.packed[0] + base.packed[1])
+    _clear_host_tiers()
+    _assert_same_assembly(warm, daysim._assemble_query(**query))
+
+
+def test_skeleton_hit_pads_lanes_with_lane_zero():
+    grid = {**SK_BASE, "platforms": ("aria2_puck_split",),
+            "policies": daysim.DEFAULT_POLICIES}      # 12 combos: 16 lanes
+    _clear_host_tiers()
+    daysim._assemble_query(**grid)
+    h0, m0 = _skeleton_counts()
+    asm = daysim._assemble_query(**{**grid, **SK_VALUES["capacity"](),
+                                    **SK_VALUES["standby_mw"]()})
+    assert _skeleton_counts() == (h0 + 1, m0)
+    n, n_b = asm.n_real, daysim.bucket_size(asm.n_real)
+    assert n < n_b
+    lanes = [*asm.dyn["const"].values(), asm.dyn["act_mult"],
+             *asm.ix.values()]
+    for leaf in lanes:
+        assert leaf.shape[0] == n_b
+        assert np.array_equal(leaf[n:], np.repeat(leaf[:1], n_b - n, 0))
+
+
+def test_skeleton_counters_fifo_bound_and_clear(monkeypatch):
+    monkeypatch.setattr(daysim, "_SKELETONS_MAX", 3)
+    _clear_host_tiers()
+    sk0 = daysim.cache_stats()["skeletons"]
+    grid = {**SK_BASE, "platforms": ("aria2_display",),
+            "schedules": ("desk_day",), "policies": ("none",)}
+
+    def structure(i: int) -> dict:
+        return {**grid, "designs": (dict(daysim.DEFAULT_DESIGNS[0],
+                                         compression=8.0 + i),)}
+
+    for i in range(5):                  # five structures, three kept
+        daysim._assemble_query(**structure(i))
+    daysim._assemble_query(**{**structure(4), "n_users": 3e6})  # hit
+    daysim._assemble_query(**{**structure(0), "n_users": 3e6})  # evicted
+    sk = daysim.cache_stats()["skeletons"]
+    assert {k: sk[k] - sk0[k] for k in ("hits", "misses", "evictions")} \
+        == {"hits": 1, "misses": 6, "evictions": 3}
+    assert sk["size"] == len(daysim._SKELETONS) == 3
+    daysim.clear_exec_cache()
+    assert daysim.cache_stats()["skeletons"] == {
+        "hits": 0, "misses": 0, "evictions": 0, "size": 0}
+
+
+def test_shared_skeleton_buffers_are_read_only():
+    _clear_host_tiers()
+    a = daysim._assemble_query(**SK_BASE)
+    b = daysim._assemble_query(**{**SK_BASE, "n_users": 4e6})
+    skel = next(iter(daysim._SKELETONS.values()))
+    for buf in skel.packed[0] + skel.packed[1]:
+        assert not buf.flags.writeable
+    assert not skel.seg_charge.flags.writeable
+    # the int32 buffers carry no value: both assemblies share them, and
+    # neither can write them
+    for i, j in ((0, 1), (1, 1)):
+        assert a.packed[i][j].dtype == np.int32
+        assert a.packed[i][j] is b.packed[i][j] is skel.packed[i][j]
+    with pytest.raises(ValueError):
+        a.ix["seg_of"][0, 0] = 1
+    with pytest.raises(ValueError):
+        a.packed[1][1][:] = 0
+    # the float32 buffers are each assembly's own copy
+    assert a.packed[1][0].dtype == np.float32
+    assert not np.shares_memory(a.packed[1][0], b.packed[1][0])
+    assert not np.shares_memory(a.packed[1][0], skel.packed[1][0])
+    before = skel.packed[1][0].tobytes()
+    a.ix["ambient"][0, 0] += 1.0
+    assert skel.packed[1][0].tobytes() == before
+    assert b.ix["ambient"][0, 0] != a.ix["ambient"][0, 0]
 
 
 def test_persistent_cache_shim(monkeypatch, tmp_path):
